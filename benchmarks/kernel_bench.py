@@ -31,6 +31,7 @@ from repro.core import FMT_IMAGENET, QuantConfig, lowbit_conv, lowbit_matmul
 from repro.kernels import (
     KERNEL_REGISTRY,
     conv_geometry,
+    default_interpret,
     im2col_conv_bytes,
     implicit_conv_bytes,
     lowbit_conv_fused,
@@ -40,6 +41,14 @@ from repro.kernels.autotune import (
     get_cache,
     time_config,
 )
+
+def _mode() -> str:
+    """How the Pallas rows of this process ran: the interpreter, or Mosaic
+    on the named device."""
+    if default_interpret():
+        return "interpret-mode"
+    return f"mosaic:{jax.devices()[0].device_kind}"
+
 
 # ResNet-20 CIFAR's dominant conv shape — the acceptance target for the
 # implicit path's traffic win (estimator, not wall clock: interpret mode
@@ -135,10 +144,10 @@ def _tuned_rows(entry, cache):
     else:
         us_tuned, tuned_cfg = time_config(spec, winner, n=5), winner
     return [
-        _row(f"{base}_default", us_default, "interpret-mode",
+        _row(f"{base}_default", us_default, _mode(),
              config=default_cfg, tuned=False,
              hbm_bytes=_entry_bytes(entry, default_cfg)),
-        _row(f"{base}_tuned", us_tuned, "interpret-mode",
+        _row(f"{base}_tuned", us_tuned, _mode(),
              config=tuned_cfg, tuned=True, cached=winner is not None,
              hbm_bytes=_entry_bytes(entry, tuned_cfg)),
     ]
@@ -178,7 +187,7 @@ def _conv_impl_rows(quick: bool):
                     x, w,
                 )
             r = _row(f"kernel/conv_{impl}_{tag}", us,
-                     "interpret-mode" if timed else "bytes-model only",
+                     _mode() if timed else "bytes-model only",
                      hbm_bytes=est[impl])
             r["im2col_over_implicit_bytes"] = round(ratio, 2)
             rows.append(r)
@@ -199,7 +208,7 @@ def run(quick: bool = True):
         args = entry.concrete_args()
         us = _time(jax.jit(fn), *args)
         rows.append(_row(f"kernel/{entry.name}_{entry.bench_tag}", us,
-                         "interpret-mode", hbm_bytes=_entry_bytes(entry)))
+                         _mode(), hbm_bytes=_entry_bytes(entry)))
         if entry.tune is not None:
             rows += _tuned_rows(entry, cache)
 
@@ -231,8 +240,7 @@ def run(quick: bool = True):
                                                    cfg_p)),
             xc, wc,
         )
-        rows.append(_row(f"kernel/lowbit_conv_fused_{tag}", us,
-                         "interpret-mode"))
+        rows.append(_row(f"kernel/lowbit_conv_fused_{tag}", us, _mode()))
     us = _time(
         jax.jit(lambda a, b: lowbit_conv(a, b, None, (1, 1), "SAME", cfg)),
         xc, wc,

@@ -15,9 +15,14 @@ import jax.numpy as jnp
 
 from repro.core import FMT_CIFAR, FMT_IMAGENET, QuantConfig
 from repro.data import make_cifar_iterator
-from repro.models.cnn import CNNConfig, apply_cnn, init_cnn
-from repro.optim import sgdm_init, sgdm_update, step_decay_schedule
-from repro.train import CheckpointManager, StragglerMonitor
+from repro.models.cnn import CNNConfig, init_cnn
+from repro.optim import sgdm_init, step_decay_schedule
+from repro.train import (
+    CheckpointManager,
+    StragglerMonitor,
+    enable_compile_cache,
+    make_cnn_train_step,
+)
 
 
 def train(variant, qcfg, args, ckpt_dir=None):
@@ -30,19 +35,7 @@ def train(variant, qcfg, args, ckpt_dir=None):
     mgr = CheckpointManager(ckpt_dir, keep=2) if ckpt_dir else None
     mon = StragglerMonitor()
 
-    @jax.jit
-    def step(params, opt, batch, i):
-        def loss_fn(p):
-            logits = apply_cnn(p, batch["image"], cfg, qcfg,
-                               jax.random.fold_in(jax.random.key(7), i))
-            ll = jax.nn.log_softmax(logits)
-            loss = -jnp.take_along_axis(ll, batch["label"][:, None], 1).mean()
-            acc = (logits.argmax(-1) == batch["label"]).mean()
-            return loss, acc
-
-        (l, a), g = jax.value_and_grad(loss_fn, has_aux=True)(params)
-        params, opt = sgdm_update(g, opt, params, lr_fn(opt.step))
-        return params, opt, l, a
+    step = jax.jit(make_cnn_train_step(cfg, qcfg, lr_fn, rounding_seed=7))
 
     accs = []
     for i in range(args.steps):
@@ -76,8 +69,10 @@ def main():
                     default="fake_quant",
                     help="arithmetic for the quantized convs/GEMMs: fake-quant "
                          "simulation or the quantized-domain Pallas kernels "
-                         "(interpret mode on CPU: slow, use tiny --steps)")
+                         "(Mosaic on a TPU; interpret mode elsewhere: slow, "
+                         "use tiny --steps)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     # the Pallas backend groups along im2col k-blocks; small blocks keep the
     # reduced CPU shapes from being all padding

@@ -15,7 +15,12 @@ from repro.configs import get_smoke_config
 from repro.configs.base import RunConfig, SHAPES
 from repro.data import make_lm_iterator
 from repro.models import lm
-from repro.train import CheckpointManager, StragglerMonitor, make_train_step
+from repro.train import (
+    CheckpointManager,
+    StragglerMonitor,
+    enable_compile_cache,
+    make_train_step,
+)
 
 
 def main():
@@ -28,6 +33,7 @@ def main():
     ap.add_argument("--microbatch", type=int, default=0)
     ap.add_argument("--no-quant", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config("glm4-9b")
     cfg = dataclasses.replace(

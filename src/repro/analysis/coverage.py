@@ -3,7 +3,7 @@
 The paper's energy argument (Sec. VII) requires *every* hot-path MAC to run
 on MLS low-bit operands — a single silently-unquantized ``dot_general`` (a
 layer that forgot its ``QuantConfig``, a backend that fell back to XLA fp32)
-voids it.  This module walks a jaxpr — recursing through ``pjit``,
+voids it.  This module walks a jaxpr — recursing through ``jit``,
 ``custom_vjp``/``custom_jvp``, ``scan``, ``while``, ``cond``, ``remat``,
 ``shard_map`` and ``pallas_call`` — and classifies every FLOP-bearing
 primitive (``dot_general``, ``conv_general_dilated``) into:
@@ -37,7 +37,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax import core as jcore
+from jax.extend import core as jcore
 
 __all__ = ["Site", "CoverageReport", "coverage_of_jaxpr", "trace_coverage"]
 
@@ -48,7 +48,7 @@ _INT_CODE_DTYPES = (jnp.uint8, jnp.int8)
 class Site:
     """One FLOP-bearing primitive instance (multiplier-weighted)."""
 
-    path: str  # scope chain, e.g. "pjit:train_step/scan/pallas:_kernel"
+    path: str  # scope chain, e.g. "jit:train_step/scan/pallas:_kernel"
     kind: str  # "dot" | "conv"
     klass: str  # "quantized" | "full_precision" | "data_movement"
     macs: int  # multiply-accumulates, weighted by loop/grid multipliers
@@ -159,8 +159,6 @@ def _scope_name(eqn) -> str | None:
     if not isinstance(name, str):
         nsi = eqn.params.get("name_and_src_info")
         name = getattr(nsi, "name", None)
-    if prim == "pjit" and name:
-        return f"pjit:{name}"
     if prim == "pallas_call":
         return f"pallas:{name}" if name else "pallas"
     if prim == "scan":

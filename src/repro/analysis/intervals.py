@@ -27,7 +27,7 @@ jaxpr in a reduced product of two domains:
 Transfer functions cover the primitive vocabulary of the shipped kernels
 (bit ops, shifts, select/where, dot_general, reductions, state
 ``get``/``swap``/``addupdate``, ``cond`` with concrete or unknown
-predicate, ``pjit`` recursion); unknown primitives degrade soundly to
+predicate, ``jit`` recursion); unknown primitives degrade soundly to
 ``Interval.top()``.  ``dot_general`` and integer add/accumulate ops record
 :class:`Accumulation` events; the prover in
 :mod:`repro.analysis.kernel_verify` checks each against the ``2^24``
@@ -45,7 +45,7 @@ import math
 from typing import Any
 
 import numpy as np
-from jax import core as jcore
+from jax.extend import core as jcore
 
 __all__ = [
     "AbsVal",
@@ -475,7 +475,7 @@ class _Interp:
     ``program_ids`` maps grid axis -> Interval (a point when the caller is
     enumerating grid steps).  Refs are ordinary vars whose AbsVal is the
     *current content bound*; get/swap/addupdate read and update it, and ref
-    vars passed into cond/pjit sub-jaxprs alias their operand so writes
+    vars passed into cond/jit sub-jaxprs alias their operand so writes
     propagate back out.
     """
 
@@ -507,9 +507,8 @@ class _Interp:
         if prim == "cond":
             self._cond(eqn, env)
             return
-        if prim in ("pjit", "closed_call", "core_call", "custom_jvp_call",
-                    "custom_vjp_call", "custom_vjp_call_jaxpr", "remat",
-                    "checkpoint"):
+        if prim in ("jit", "closed_call", "call", "custom_jvp_call",
+                    "custom_vjp_call", "remat2"):
             self._call(eqn, env)
             return
 

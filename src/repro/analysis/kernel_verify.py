@@ -45,7 +45,9 @@ if TYPE_CHECKING:  # registry imports kernels; keep runtime import lazy
 
 import jax
 import jax.numpy as jnp
-from jax import core as jcore
+from jax.core import eval_jaxpr
+from jax.experimental import pallas as pl
+from jax.extend import core as jcore
 
 from repro.core.formats import EMFormat, FMT_IMAGENET, GS_FMT_DEFAULT
 from repro.core.lowbit import QuantConfig
@@ -191,9 +193,16 @@ class KernelReport:
 # ---------------------------------------------------------------------------
 # coverage proofs
 # ---------------------------------------------------------------------------
+def _block_sizes(bm) -> tuple[int, ...]:
+    """Block extent per dim (``Blocked`` sizes; a squeezed dim is 1)."""
+    return tuple(
+        1 if isinstance(b, pl.Squeezed) else b.block_size
+        for b in bm.block_shape)
+
+
 def _eval_index_map(bm, point: tuple[int, ...]) -> tuple[int, ...]:
     im = bm.index_map_jaxpr
-    res = jcore.eval_jaxpr(im.jaxpr, im.consts, *point)
+    res = eval_jaxpr(im.jaxpr, im.consts, *point)
     return tuple(int(r) for r in res)
 
 
@@ -218,8 +227,8 @@ def _check_operand(
     is_output: bool,
 ) -> tuple[list[Violation], dict | None]:
     viols: list[Violation] = []
-    shape = tuple(int(s) for s in bm.array_shape_dtype.shape)
-    bs = tuple(int(b) for b in bm.block_shape)
+    shape = tuple(int(s) for s in bm.array_aval.shape)
+    bs = _block_sizes(bm)
     for i, (s, b) in enumerate(zip(shape, bs)):
         if b < 1 or s % b:
             viols.append(Violation(
@@ -289,10 +298,9 @@ def _prove_body(eqn, grid: tuple[int, ...]):
     exhaustive = True
 
     seeds = [
-        Interval.of_dtype(bm.block_aval.inner_aval.dtype)
-        for bm in gm.block_mappings
+        Interval.of_dtype(bm.array_aval.dtype) for bm in gm.block_mappings
     ]
-    seeds += [Interval.top()] * int(gm.num_scratch_operands)
+    seeds += [Interval.top()] * len(gm.scratch_avals)
     if len(seeds) != len(body.invars):
         warnings.append(
             f"body has {len(body.invars)} invars but {len(seeds)} block "
@@ -528,8 +536,9 @@ def prove_window_grid(
     """Coverage proof for the implicit-GEMM conv's halo'd window grid.
 
     The implicit kernel's activation BlockSpec fetches whole images — the
-    actual patch addressing is the in-kernel halo-band load plus static
-    strided tap slices, which the generic index-map enumeration cannot see.
+    actual patch addressing is the in-kernel strided tap loads from the
+    halo band of each M-tile, which the generic index-map enumeration
+    cannot see.
     This replays that address arithmetic over the full grid and proves:
 
     * every halo band ``[row0, row0 + band_h)`` stays inside the padded

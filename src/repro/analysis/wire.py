@@ -28,13 +28,6 @@ from repro.core import FMT_IMAGENET, EMFormat
 __all__ = ["audit_wire_ring"]
 
 
-def _shard_map():
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:  # jax < 0.6 keeps it in experimental
-        from jax.experimental.shard_map import shard_map as sm
-    return sm
-
-
 def audit_wire_ring(
     n_elems: int = 1 << 16,
     n_pods: int = 2,
@@ -55,7 +48,7 @@ def audit_wire_ring(
 
     mesh = make_mesh((n_pods,), ("pod",))
 
-    @partial(_shard_map(), mesh=mesh, in_specs=P("pod", None),
+    @partial(jax.shard_map, mesh=mesh, in_specs=P("pod", None),
              out_specs=P("pod", None))
     def ring(x):  # x: (1, n_elems) per pod
         return crosspod_allreduce_mean(x[0], "pod", fmt=fmt)[None]

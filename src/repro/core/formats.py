@@ -31,6 +31,7 @@ __all__ = [
     "GS_FMT_DEFAULT",
     "accumulation_bits",
     "exponent_fraction",
+    "pow2",
     "srandom_like",
 ]
 
@@ -157,6 +158,15 @@ def exponent_fraction(x: jax.Array):
     e = jnp.where(is_zero | is_sub, jnp.int32(-(2**30)), e)
     frac = jnp.where(is_zero | is_sub, 0.0, frac)
     return e, frac
+
+
+def pow2(e: jax.Array) -> jax.Array:
+    """Exact ``2.0 ** e`` in fp32 for integer ``e`` in the normal range
+    [-126, 127], built from the exponent field.  ``jnp.exp2`` is not exact
+    on every backend (XLA's CPU exp2 is off by ulps below 2^-13), and MLS
+    scales and grid steps must be true powers of two."""
+    e = jnp.asarray(e).astype(jnp.int32)
+    return jax.lax.bitcast_convert_type((e + 127) << 23, jnp.float32)
 
 
 def srandom_like(key: jax.Array, x: jax.Array) -> jax.Array:

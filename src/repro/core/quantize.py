@@ -25,7 +25,7 @@ from collections.abc import Sequence
 import jax
 import jax.numpy as jnp
 
-from .formats import EMFormat, GS_FMT_DEFAULT, exponent_fraction, srandom_like
+from .formats import EMFormat, GS_FMT_DEFAULT, exponent_fraction, pow2, srandom_like
 
 __all__ = [
     "GroupSpec",
@@ -129,9 +129,7 @@ def quantize_group_scale(s_gf: jax.Array, gs_fmt: EMFormat):
     overflow = man >= 2**gs_fmt.m
     man = jnp.where(overflow, 0, man)
     e = jnp.clip(jnp.where(overflow, e + 1, e), e_min, 0)
-    s_g = (1.0 + man.astype(jnp.float32) * 2.0**-gs_fmt.m) * jnp.exp2(
-        e.astype(jnp.float32)
-    )
+    s_g = (1.0 + man.astype(jnp.float32) * 2.0**-gs_fmt.m) * pow2(e)
     return s_g, (-e).astype(jnp.int32), man
 
 
@@ -162,7 +160,7 @@ def quantize_elements(
         e_eff = jnp.clip(e, fmt.e_min, -1)
         # step = 2^(e_eff - M): grid spacing at this exponent level (covers
         # denormals too: at e_min the denormal step equals the normal step).
-        step = jnp.exp2((e_eff - fmt.m).astype(jnp.float32))
+        step = pow2(e_eff - fmt.m)
         scaled = x_f / step
         if r is not None:
             q = jnp.floor(scaled + r + 0.5)
@@ -339,7 +337,7 @@ def unpack_elements(code: jax.Array, fmt: EMFormat):
     top = 2**fmt.e - 1
     is_denorm = exp == 0
     frac = jnp.where(is_denorm, 0.0, 1.0) + man.astype(jnp.float32) * 2.0**-fmt.m
-    mag = frac * jnp.exp2(-jnp.where(is_denorm, top, exp).astype(jnp.float32))
+    mag = frac * pow2(-jnp.where(is_denorm, top, exp))
     sign = 1.0 - 2.0 * sign_bit.astype(jnp.float32)
     # zero has man==0, exp==0 (denormal) -> mag 0; sign bit irrelevant
     return sign, mag

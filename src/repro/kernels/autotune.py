@@ -16,11 +16,12 @@ shapes.  This module searches tiling candidates per *tuning key* —
   Illegal tilings are never timed (and never cost a Mosaic compile).
 * **Timing**: survivors run through the real fused pipeline
   (``lowbit_matmul_fused`` / ``mls_quantize_pallas``), best-of-n.
-* **Persistence**: winners land in a JSON cache — ``.cache/kernel_tune.json``
-  by default, overridable via the ``REPRO_TUNE_CACHE`` env var or an
-  explicit path — merged over the committed seed cache
-  (``kernels/tuned/kernel_tune.json``) that CI keeps fresh with
-  ``python -m repro.kernels.autotune --check``.
+* **Persistence**: ``--tune`` writes winners to a JSON cache —
+  ``$REPRO_TUNE_CACHE``, else ``.cache/kernel_tune.json``.  Resolution
+  reads the committed seed cache (``kernels/tuned/kernel_tune.json``, kept
+  fresh by CI with ``python -m repro.kernels.autotune --check``) plus an
+  overlay only when ``REPRO_TUNE_CACHE`` names one, so an uncommitted local
+  file never changes what a run executes.
 
 Hot-path resolution (:func:`resolve_block_config`) never times or traces:
 **explicit override > cache hit > proven-legal default**, where the default
@@ -277,14 +278,14 @@ _CACHE: TuneCache | None = None
 
 
 def get_cache() -> TuneCache:
-    """The process-wide resolution cache, loaded once: the local cache
-    (``REPRO_TUNE_CACHE`` env or ``.cache/kernel_tune.json``) merged over
-    the committed seed cache."""
+    """The process-wide resolution cache, loaded once: the committed seed
+    cache, overlaid with the cache ``REPRO_TUNE_CACHE`` names when set."""
     global _CACHE
     if _CACHE is None:
-        local = TuneCache.load(
-            os.environ.get(CACHE_ENV_VAR, DEFAULT_CACHE_PATH))
-        _CACHE = local.merged_over(TuneCache.load(SEED_CACHE_PATH))
+        _CACHE = TuneCache.load(SEED_CACHE_PATH)
+        overlay = os.environ.get(CACHE_ENV_VAR)
+        if overlay:
+            _CACHE = TuneCache.load(overlay).merged_over(_CACHE)
     return _CACHE
 
 

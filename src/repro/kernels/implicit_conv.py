@@ -21,26 +21,28 @@ with ``M0 = N*OH*OW`` patch rows and ``K0 = C*kh*kw`` features in
   Tiles therefore never straddle an image, an output row, or a channel's
   taps, so no M/K padding exists and scaling groups are exactly whole
   channels' taps — the conv analogue of the paper's (n, c) grouping.
-* The activation arrives spatially pre-padded as full-image blocks
-  ``(1, C, Hp, Wp)`` whose index map depends only on the image index
-  ``i // (OH/bh)``: consecutive grid steps (all j, k, and same-image row
-  tiles) keep the same block index, so Pallas fetches each image from HBM
-  **once** — the "activations read once" property the ROADMAP asks for.
+* The activation arrives spatially pre-padded in a channel-blocked
+  channels-last layout ``(N, C/cb, Hp, Wp, cb)`` as whole-image blocks
+  whose index map depends only on the image index ``i // (OH/bh)``:
+  consecutive grid steps (all j, k, and same-image row tiles) keep the same
+  block index, so Pallas fetches each image from HBM **once** — the
+  "activations read once" property the ROADMAP asks for.
 * Inside the kernel, a program decodes its ``(i, k)`` grid coordinates into
-  an ``(n, c0, h-band)`` window: it loads the ``band_h = sh*(bh-1)+kh`` halo
-  band of rows its output rows need, gathers the ``kh*kw`` tap planes with
-  static strided slices, and transposes them into the ``(bm, kb)`` GEMM
-  tile.
-* The quantize prologue then runs paper Alg. 2 **in VMEM** on that tile —
-  in-kernel group maxima for ``"nc"``, precomputed compact scales for
-  ``"c"``/``"n"``/``"none"`` — reusing the exact helpers of
+  an ``(n, channel-block, h-band)`` window and reads each of the ``kh*kw``
+  taps as one strided ``(bh, OW, cb)`` load of that band — a ``(bm, cb)``
+  GEMM operand with rows in patch order and channels on the lanes.  The
+  ``(bm, kb)`` tile of the virtual GEMM is the concatenation of those taps,
+  so the tile's dot is the sum of one 2-D dot per tap.
+* The quantize prologue then runs paper Alg. 2 **in VMEM** on the taps —
+  the ``"nc"`` group maximum of a row is the maximum over its taps, the
+  element codes are elementwise — reusing the exact helpers of
   :mod:`repro.kernels.mls_quantize`, so codes and scales are bit-identical
   to the im2col pipeline with ``k_block = kb``.  Neither fp32 patches nor
   intermediate codes ever round-trip through HBM.
 * The epilogue is :mod:`repro.kernels.mls_matmul`'s: decode to integer
-  fractions, MXU dot (exact fp32 integer MACs, < 2^24), inter-group scale
-  ``s_g^x * s_g^w``, and a final ``s_t^x * s_t^w * unit`` on the output
-  tile.
+  fractions, MXU dots (exact fp32 integer MACs, < 2^24; summing the per-tap
+  dots of one group stays exact), inter-group scale ``s_g^x * s_g^w``, and
+  a final ``s_t^x * s_t^w * unit`` on the output tile.
 
 Legality: the layout requires ``k_block = cb * kh * kw`` with ``cb | C``
 (:func:`implicit_compatible`).  Incompatible configs keep the im2col path —
@@ -67,8 +69,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.formats import EMFormat, GS_FMT_DEFAULT
 from repro.core.quantize import quantize_group_scale
-from .mls_matmul import _decode_frac, _sg_specs, sg_shapes
-from .mls_quantize import GROUPINGS, _element_codes, _quantize_block
+from .mls_matmul import _decode_frac, fraction_dot, scale_operand
+from .mls_quantize import (
+    GROUPINGS,
+    SMEM_SPEC,
+    _element_codes,
+    rounding_offset,
+)
 from .runtime import resolve_interpret
 
 __all__ = [
@@ -368,43 +375,37 @@ def _implicit_x_scales(xp, geom: ConvGeom, fmt, gs_fmt, kb, grouping):
 # ---------------------------------------------------------------------------
 # The fused kernel
 # ---------------------------------------------------------------------------
-def _gather_tile(x_ref, geom: ConvGeom, bh: int, cb: int, bm: int, kb: int):
-    """Decode (i, k) grid coords into the (bm, kb) implicit-GEMM tile.
+def _tap_loads(x_ref, geom: ConvGeom, bh: int):
+    """The ``kh*kw`` taps of this program's window as ``(bm, cb)`` operands.
 
-    Loads the halo band of input rows this program's output rows touch,
-    then gathers the kh*kw tap planes with static strided slices — the
-    "index map" of the implicit GEMM, executed on VMEM-resident data.
+    Decodes the (i, k) grid coordinates into an (image, channel block,
+    output-row band) window and reads each tap with one strided load of the
+    whole-image block — the "index map" of the implicit GEMM, executed on
+    VMEM-resident data.  Tap ``(kh_, kw_)`` of output pixel ``(r, c)`` is
+    padded-input pixel ``(row0 + kh_ + sh*r, kw_ + sw*c)``; the halo band is
+    rows ``[row0, row0 + sh*(bh-1) + kh)``.
     """
     i = pl.program_id(0)
     k = pl.program_id(2)
-    oh_tiles = geom.oh // bh
-    band_h = geom.sh * (bh - 1) + geom.kh
-    row0 = (i % oh_tiles) * bh * geom.sh
-    c0 = k * cb
-    band = pl.load(
-        x_ref,
-        (pl.dslice(0, 1), pl.dslice(c0, cb), pl.dslice(row0, band_h),
-         pl.dslice(0, geom.wp)),
-    )[0]  # (cb, band_h, Wp)
+    row0 = (i % (geom.oh // bh)) * bh * geom.sh
+    cb = x_ref.shape[-1]
     taps = []
     for kh_ in range(geom.kh):
         for kw_ in range(geom.kw):
-            taps.append(band[
+            a = x_ref[
+                0, k,
+                pl.ds(row0 + kh_, bh, stride=geom.sh),
+                pl.ds(kw_, geom.ow, stride=geom.sw),
                 :,
-                kh_: kh_ + 1 + geom.sh * (bh - 1): geom.sh,
-                kw_: kw_ + 1 + geom.sw * (geom.ow - 1): geom.sw,
-            ])  # (cb, bh, OW)
-    g = jnp.stack(taps, axis=1)  # (cb, KK, bh, OW)
-    # rows: (oh_local, ow) = patch order; cols: (c_local, kh, kw) = the
-    # im2col feature order restricted to this k-block.
-    return g.transpose(2, 3, 0, 1).reshape(bm, kb)
+            ]  # (bh, OW, cb): rows in (oh_local, ow) patch order
+            taps.append(a.reshape(bh * geom.ow, cb))
+    return taps
 
 
 def _implicit_kernel(
     *refs, geom: ConvGeom, fmt: EMFormat, gs_fmt: EMFormat, grouping: str,
-    stochastic: bool, emit: bool, bh: int, cb: int, n_k: int,
+    stochastic: bool, emit: bool, bh: int, n_k: int, x_scale, w_scale,
 ):
-    bm, kb = bh * geom.ow, cb * geom.kk
     it = iter(refs)
     x_ref = next(it)
     r_ref = next(it) if stochastic else None
@@ -424,42 +425,37 @@ def _implicit_kernel(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = _gather_tile(x_ref, geom, bh, cb, bm, kb)
-    r = r_ref[...] if stochastic else jnp.full((bm, kb), 127, jnp.uint8)
+    taps = _tap_loads(x_ref, geom, bh)
+    s_t = stx_ref[0, 0]
 
-    # ---- quantize prologue: paper Alg. 2 on the VMEM tile ----------------
-    if grouping == "nc":
-        codes, s_g = _quantize_block(a, r, stx_ref[0, 0], fmt, gs_fmt)
-        xs = s_g[:, None]  # (bm, 1): the matmul-side compact scale block
-    else:
-        xs = xsg_ref[...]  # (1,1) for "c"/"none", (bm,1) for "n"
-        codes = _element_codes(a, r, stx_ref[0, 0] * xs, fmt)
+    # ---- quantize prologue: paper Alg. 2 on the VMEM taps ----------------
+    if grouping == "nc":  # one group per row: the max over all its taps
+        s_r = functools.reduce(jnp.maximum, [
+            jnp.max(jnp.abs(a), axis=1, keepdims=True) for a in taps])
+        xs, _, _ = quantize_group_scale(s_r / s_t, gs_fmt)  # (bm, 1)
+    else:  # (bm, 1) for "n", a scalar for "c"/"none"
+        xs = x_scale(xsg_ref, k, 0)
+    denom = s_t * xs
 
-    # ---- GEMM body: identical to mls_matmul's _kernel --------------------
-    fx = _decode_frac(codes, fmt)
-    fw = _decode_frac(wc_ref[...], fmt)
-    p = jnp.dot(fx, fw, preferred_element_type=jnp.float32)
-    sp = xs * wsg_ref[...]
-    acc_ref[...] += p * sp
+    # ---- GEMM body: mls_matmul's, one dot per tap -------------------------
+    p = None
+    for t, a in enumerate(taps):
+        r = rounding_offset(r_ref[0, t]) if stochastic else None
+        codes = _element_codes(a, r, denom, fmt)
+        if emit:
+            codes_ref[0, t] = codes
+        pt = fraction_dot(_decode_frac(codes, fmt),
+                          _decode_frac(wc_ref[0, t], fmt), fmt)
+        p = pt if p is None else p + pt
+    acc_ref[...] += p * (xs * w_scale(wsg_ref, k, 0))
 
     @pl.when(k == n_k - 1)
     def _done():
         unit = 2.0 ** (2 * (fmt.e_min - fmt.m))
         out_ref[...] = acc_ref[...] * (stp_ref[0, 0] * unit)
 
-    if emit:
-        codes_ref[...] = codes
-        if sgo_ref is not None:
-            sgo_ref[...] = xs
-
-
-def _xsg_spec(grouping: str, bm: int):
-    """BlockSpec for the precomputed compact activation scales."""
-    if grouping == "c":
-        return pl.BlockSpec((1, 1), lambda i, j, k: (0, k))
-    if grouping == "n":
-        return pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0))
-    return pl.BlockSpec((1, 1), lambda i, j, k: (0, 0))  # "none"
+    if sgo_ref is not None:
+        sgo_ref[0] = xs
 
 
 def implicit_conv_forward(
@@ -531,53 +527,66 @@ def implicit_conv_forward(
         wp.T, fmt, kb, gs_fmt, key_w, block_m=block_n, interpret=interpret,
         grouping=grouping,
     )
-    wcT, wsg = wc.T, wsgT.T
+    wsg = wsgT.T
     stp = (s_t * wst).astype(jnp.float32).reshape(1, 1)
 
     stochastic = key_x is not None
     oh_tiles = geom.oh // bh
+    kk = geom.kk
     grid = (geom.m0 // bm, op // bn, n_k)
 
+    # Channel-blocked channels-last activation: (N, C/cb, Hp, Wp, cb).
+    x5 = xp.reshape(geom.n, n_k, cb, geom.hp, geom.wp).transpose(0, 1, 3, 4, 2)
     in_specs = [
-        pl.BlockSpec((1, geom.c, geom.hp, geom.wp),
-                     lambda i, j, k, t=oh_tiles: (i // t, 0, 0, 0)),
+        pl.BlockSpec((1, n_k, geom.hp, geom.wp, cb),
+                     lambda i, j, k, t=oh_tiles: (i // t, 0, 0, 0, 0)),
     ]
-    operands = [xp]
+    operands = [x5]
+    # Per-tap operand layout of a (rows, K0) array whose K0 is in
+    # (c, kh, kw) order: (n_k, kh*kw, rows, cb).
+    def per_tap(a):
+        return a.reshape(a.shape[0], n_k, cb, kk).transpose(1, 3, 0, 2)
+
     if stochastic:
         r_u8 = jax.random.randint(
             key_x, (geom.m0, geom.k0), 0, 256, dtype=jnp.int32
         ).astype(jnp.uint8)
-        in_specs.append(pl.BlockSpec((bm, kb), lambda i, j, k: (i, k)))
-        operands.append(r_u8)
-    in_specs += [
-        pl.BlockSpec((1, 1), lambda i, j, k: (0, 0)),  # stx
-        pl.BlockSpec((1, 1), lambda i, j, k: (0, 0)),  # stp
-    ]
+        in_specs.append(
+            pl.BlockSpec((1, kk, bm, cb), lambda i, j, k: (k, 0, i, 0)))
+        operands.append(per_tap(r_u8))
+    in_specs += [SMEM_SPEC, SMEM_SPEC]  # stx, stp
     operands += [stx, stp]
+    x_scale = None
     if grouping != "nc":
-        in_specs.append(_xsg_spec(grouping, bm))
+        x_sg, xsg_spec, x_scale = scale_operand(
+            x_sg, "x", grouping, n_k, 1, bm, grid_axis=0)
+        in_specs.append(xsg_spec)
         operands.append(x_sg)
-    wsg_spec = _sg_specs(grouping, bm, bn)[1]
+    wsg, wsg_spec, w_scale = scale_operand(
+        wsg, "w", grouping, n_k, 1, bn, grid_axis=1)
     in_specs += [
-        pl.BlockSpec((kb, bn), lambda i, j, k: (k, j)),
+        pl.BlockSpec((1, kk, cb, bn), lambda i, j, k: (k, 0, 0, j)),
         wsg_spec,
     ]
-    operands += [wcT, wsg]
+    operands += [per_tap(wc).transpose(0, 1, 3, 2), wsg]  # (n_k, kk, cb, Op)
 
     out_specs = [pl.BlockSpec((bm, bn), lambda i, j, k: (i, j))]
     out_shape = [jax.ShapeDtypeStruct((geom.m0, op), jnp.float32)]
     if emit_codes:
-        out_specs.append(pl.BlockSpec((bm, kb), lambda i, j, k: (i, k)))
-        out_shape.append(jax.ShapeDtypeStruct((geom.m0, geom.k0), jnp.uint8))
+        out_specs.append(
+            pl.BlockSpec((1, kk, bm, cb), lambda i, j, k: (k, 0, i, 0)))
+        out_shape.append(
+            jax.ShapeDtypeStruct((n_k, kk, geom.m0, cb), jnp.uint8))
         if grouping == "nc":
-            out_specs.append(pl.BlockSpec((bm, 1), lambda i, j, k: (i, k)))
+            out_specs.append(
+                pl.BlockSpec((1, bm, 1), lambda i, j, k: (k, i, 0)))
             out_shape.append(
-                jax.ShapeDtypeStruct((geom.m0, n_k), jnp.float32))
+                jax.ShapeDtypeStruct((n_k, geom.m0, 1), jnp.float32))
 
     kernel = functools.partial(
         _implicit_kernel, geom=geom, fmt=fmt, gs_fmt=gs_fmt,
         grouping=grouping, stochastic=stochastic, emit=emit_codes,
-        bh=bh, cb=cb, n_k=n_k,
+        bh=bh, n_k=n_k, x_scale=x_scale, w_scale=w_scale,
     )
     res = pl.pallas_call(
         kernel,
@@ -594,9 +603,11 @@ def implicit_conv_forward(
     y = y.transpose(0, 3, 1, 2)
     if not emit_codes:
         return y
-    codes = res[1]
+    # back to the im2col layout: (n_k, kk, M0, cb) -> (M0, K0) in
+    # (c, kh, kw) feature order
+    codes = res[1].transpose(2, 0, 3, 1).reshape(geom.m0, geom.k0)
     if grouping == "nc":
-        x_sg = res[2]
+        x_sg = res[2][:, :, 0].T  # (M0, n_k)
     return y, codes, x_sg, s_t
 
 
@@ -607,8 +618,7 @@ def elementwise_codes(v: jax.Array, s_t: jax.Array, fmt: EMFormat):
     """Deterministic element codes against a tensor-wide scale (the
     ``grouping="none"`` quantizer, r = 127) — exactly the forward kernel's
     prologue, so gathering these *is* reusing the forward codes."""
-    r = jnp.full(v.shape, 127, jnp.uint8)
-    return _element_codes(v.astype(jnp.float32), r, s_t, fmt)
+    return _element_codes(v.astype(jnp.float32), None, s_t, fmt)
 
 
 def patches_u8(xq: jax.Array, geom: ConvGeom) -> jax.Array:
@@ -693,6 +703,16 @@ def implicit_conv_bytes(
     scale precompute, and fetched into VMEM **once per image** by the
     kernel (the full-image block's index map only changes with the image
     index).  No patch matrix, no activation-code round-trip.
+
+    Left out: the relayouts the kernel's operand layouts cost.  The
+    compiled v5e program writes the channel-blocked channels-last copy of
+    the padded activation beside the padded one, in the same fusion (one
+    more activation write), and, stochastic, re-lays the rounding bits out
+    per tap (one more read and write of ``M0*K0`` bytes).  Arrays whose
+    minor dim is ``cb`` or ``O`` < 128 are stored lane-padded to 128 on the
+    chip, which is not modelled either.  The per-tap weight-code transpose
+    is a few KiB, ignored like the scales (the im2col model ignores its
+    own ``wc.T`` too).
     """
     bh_d, bn_d = default_conv_blocks(geom)
     bh = bh_d if bh is None else bh

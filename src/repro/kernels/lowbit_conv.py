@@ -214,6 +214,11 @@ def qd_gemm(
 # ---------------------------------------------------------------------------
 # im2col layout
 # ---------------------------------------------------------------------------
+# The patch gather is an identity-filter convolution: at the TPU's default
+# precision it would round activations to bf16 before they are quantized.
+_EXACT = jax.lax.Precision.HIGHEST
+
+
 def _im2col(x: jax.Array, ksize: tuple[int, int], stride, padding):
     """NCHW -> (N*OH*OW, C*kh*kw) patch matrix (+ output spatial dims).
 
@@ -222,7 +227,7 @@ def _im2col(x: jax.Array, ksize: tuple[int, int], stride, padding):
     """
     p = jax.lax.conv_general_dilated_patches(
         x.astype(jnp.float32), ksize, stride, padding,
-        dimension_numbers=("NCHW", "OIHW", "NCHW"),
+        dimension_numbers=("NCHW", "OIHW", "NCHW"), precision=_EXACT,
     )
     n, ckk, oh, ow = p.shape
     return p.transpose(0, 2, 3, 1).reshape(n * oh * ow, ckk), (n, oh, ow)
@@ -237,7 +242,7 @@ def _col2im(dcols: jax.Array, x_shape, ksize, stride, padding, out_hw):
     def patches(a):
         return jax.lax.conv_general_dilated_patches(
             a, ksize, stride, padding,
-            dimension_numbers=("NCHW", "OIHW", "NCHW"),
+            dimension_numbers=("NCHW", "OIHW", "NCHW"), precision=_EXACT,
         )
 
     transpose = jax.linear_transpose(

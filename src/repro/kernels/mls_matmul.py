@@ -17,17 +17,21 @@ adapted to TPU as a tiled matmul:
 * The tensor scales ``s_t^x * s_t^w`` multiply the output tile once
   (paper Sec. V-B: tensor-wise scale folded out of the MAC array).
 
-Grid: ``(M/bm, N/bn, K/bk)`` with the contraction innermost; ``bk`` equals
-the scaling-group width so group boundaries coincide with VMEM tiles.
+Grid: ``(M/bm, N/bn, K/kt)`` with the contraction innermost; the
+contraction tile ``kt`` (:func:`repro.kernels.mls_quantize.k_tile`) holds a
+whole number of scaling groups, which the kernel contracts one by one with
+static slices, so group boundaries never straddle a VMEM tile.  The decoded
+fractions enter the MXU as bf16 whenever they fit its 8-bit significand
+(``M <= 7``, every paper format) — still exact integer arithmetic.
 
 **Grouping is a first-class kernel parameter** (paper Table IV): the
-group-scale operands arrive in the compact layout of the grouping and the
-BlockSpecs are reshaped per layout — ``"nc"`` (per row x k-block / k-block
-x column), ``"c"`` (per k-block, shared across rows/columns), ``"n"`` (per
-row / per column, constant along K) or ``"none"`` (all-ones, the tensor
-scale carries everything).  See :func:`sg_shapes` for the exact layouts.
-The kernel body is layout-generic: the scale blocks broadcast against the
-(bm, bn) partial-product tile.
+group-scale operands arrive in the compact layout of the grouping (see
+:func:`sg_shapes`) and are laid out per grouping for the TPU: per-row and
+per-column scales as VMEM blocks whose minor dims are lane-dense or whole
+(``"nc"`` reshaped to ``(K/kt, rows, groups)``), per-group scalars
+(``"c"``, ``"none"``) as flat SMEM arrays.  The kernel body is
+layout-generic: each group's scale pair broadcasts against the (bm, bn)
+partial-product tile.
 """
 from __future__ import annotations
 
@@ -39,6 +43,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.formats import EMFormat
+from .mls_quantize import SMEM_SPEC, k_tile, padded_k
 from .runtime import resolve_interpret
 
 GROUPINGS = ("nc", "c", "n", "none")
@@ -64,26 +69,53 @@ def sg_shapes(
     raise ValueError(f"unknown grouping {grouping!r}; expected {GROUPINGS}")
 
 
-def _sg_specs(grouping: str, block_m: int, block_n: int):
-    """BlockSpecs delivering the right scale slice per grid point."""
+def scale_operand(sg, side: str, grouping: str, nkt: int, gpt: int,
+                  block: int, grid_axis: int):
+    """Lay one operand's compact group scales out for the TPU kernels.
+
+    ``side`` is ``"x"`` (rows x groups) or ``"w"`` (groups x columns);
+    ``block`` is the row (x) or column (w) tile and ``grid_axis`` the grid
+    axis that walks it.  Returns ``(array, BlockSpec, get)`` where
+    ``get(ref, k, g)`` reads the scale of group ``g`` of contraction tile
+    ``k`` as a (bm, 1) / (1, bn) column or row, or a scalar.
+    """
+    def at(idx):  # index map putting `idx` where the grid walks the tile
+        def im(*ids):
+            return idx(ids[grid_axis], ids[-1])
+        return im
+
     if grouping == "nc":
-        return (
-            pl.BlockSpec((block_m, 1), lambda i, j, k: (i, k)),
-            pl.BlockSpec((1, block_n), lambda i, j, k: (k, j)),
-        )
-    if grouping == "c":
-        return (
-            pl.BlockSpec((1, 1), lambda i, j, k: (0, k)),
-            pl.BlockSpec((1, 1), lambda i, j, k: (k, 0)),
-        )
+        if side == "x":  # (M, nkb) -> (nkt, M, gpt)
+            arr = sg.reshape(sg.shape[0], nkt, gpt).transpose(1, 0, 2)
+            spec = pl.BlockSpec((1, block, gpt), at(lambda b, k: (k, b, 0)))
+            return arr, spec, lambda ref, k, g: ref[0, :, g:g + 1]
+        arr = sg.reshape(nkt, gpt, sg.shape[1])  # (nkb, N) -> (nkt, gpt, N)
+        spec = pl.BlockSpec((1, gpt, block), at(lambda b, k: (k, 0, b)))
+        return arr, spec, lambda ref, k, g: ref[0, g:g + 1, :]
     if grouping == "n":
-        return (
-            pl.BlockSpec((block_m, 1), lambda i, j, k: (i, 0)),
-            pl.BlockSpec((1, block_n), lambda i, j, k: (0, j)),
-        )
-    return (  # "none"
-        pl.BlockSpec((1, 1), lambda i, j, k: (0, 0)),
-        pl.BlockSpec((1, 1), lambda i, j, k: (0, 0)),
+        if side == "x":  # (M, 1)
+            spec = pl.BlockSpec((block, 1), at(lambda b, k: (b, 0)))
+        else:  # (1, N)
+            spec = pl.BlockSpec((1, block), at(lambda b, k: (0, b)))
+        return sg, spec, lambda ref, k, g: ref[...]
+    flat = sg.reshape(-1)  # "c": one scalar per group; "none": one scalar
+    if grouping == "c":
+        return flat, SMEM_SPEC, lambda ref, k, g: ref[k * gpt + g]
+    return flat, SMEM_SPEC, lambda ref, k, g: ref[0]
+
+
+def mxu_dtype(fmt: EMFormat):
+    """Operand dtype of the integer-fraction dot: bf16 holds every |F| <
+    2^(M+1) << (2^E - 2) exactly while M + 1 <= 8 significand bits."""
+    return jnp.bfloat16 if fmt.m <= 7 else jnp.float32
+
+
+def fraction_dot(fx, fw, fmt: EMFormat):
+    """Exact integer-fraction product on the MXU (fp32 accumulation)."""
+    dt = mxu_dtype(fmt)
+    return jnp.dot(
+        fx.astype(dt), fw.astype(dt), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST if dt == jnp.float32 else None,
     )
 
 
@@ -102,7 +134,8 @@ def _decode_frac(codes, fmt: EMFormat):
 
 
 def _kernel(
-    xc_ref, xsg_ref, wc_ref, wsg_ref, st_ref, out_ref, acc_ref, *, fmt, n_k
+    xc_ref, xsg_ref, wc_ref, wsg_ref, st_ref, out_ref, acc_ref, *, fmt, n_k,
+    k_block, x_scale, w_scale,
 ):
     k = pl.program_id(2)
 
@@ -110,15 +143,17 @@ def _kernel(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    fx = _decode_frac(xc_ref[...], fmt)  # (bm, bk) exact small ints
-    fw = _decode_frac(wc_ref[...], fmt)  # (bk, bn)
-    # Intra-group integer MACs on the MXU (exact in fp32, see module doc).
-    p = jnp.dot(fx, fw, preferred_element_type=jnp.float32)  # (bm, bn)
-    # Inter-group scale S_p = s_g^x ⊗ s_g^w (shift-add in HW, exact here).
-    # The scale blocks are (bm|1, 1) x (1, bn|1) depending on the grouping
-    # layout; the product broadcasts against the (bm, bn) partial tile.
-    sp = xsg_ref[...] * wsg_ref[...]
-    acc_ref[...] += p * sp
+    fx = _decode_frac(xc_ref[...], fmt)  # (bm, kt) exact small ints
+    fw = _decode_frac(wc_ref[...], fmt)  # (kt, bn)
+    acc = acc_ref[...]
+    for g in range(fx.shape[1] // k_block):  # the tile's scaling groups
+        sl = slice(g * k_block, (g + 1) * k_block)
+        # Intra-group integer MACs on the MXU (exact, see module doc).
+        p = fraction_dot(fx[:, sl], fw[sl, :], fmt)  # (bm, bn)
+        # Inter-group scale S_p = s_g^x * s_g^w (shift-add in HW, exact
+        # here), broadcast against the (bm, bn) partial tile.
+        acc = acc + p * (x_scale(xsg_ref, k, g) * w_scale(wsg_ref, k, g))
+    acc_ref[...] = acc
 
     @pl.when(k == n_k - 1)
     def _done():
@@ -132,16 +167,6 @@ def _nearest_legal_block(extent: int, block: int) -> int:
         if extent % b == 0:
             return b
     return 1
-
-
-def _pad_rows(x: jax.Array, mult: int) -> jax.Array:
-    p = (-x.shape[0]) % mult
-    return jnp.pad(x, ((0, p), (0, 0))) if p else x
-
-
-def _pad_cols(x: jax.Array, mult: int) -> jax.Array:
-    p = (-x.shape[1]) % mult
-    return jnp.pad(x, ((0, 0), (0, p))) if p else x
 
 
 def mls_matmul_pallas(
@@ -192,31 +217,43 @@ def mls_matmul_pallas(
             f"{tuple(x_sg.shape)} / {tuple(w_sg.shape)}"
         )
 
-    # Pad ragged M/N tails to block multiples (exact: zero codes decode to
-    # 0; padded scale rows/cols are 1.0 so no inf/nan can leak into 0 * sp).
-    pm, pn = (-M) % block_m, (-N) % block_n
-    if pm:
-        x_codes = _pad_rows(x_codes, block_m)
-        if grouping in ("nc", "n"):
-            x_sg = jnp.pad(x_sg, ((0, pm), (0, 0)), constant_values=1.0)
-    if pn:
-        w_codes = _pad_cols(w_codes, block_n)
-        if grouping in ("nc", "n"):
-            w_sg = jnp.pad(w_sg, ((0, 0), (0, pn)), constant_values=1.0)
+    # Pad ragged M/N tails to block multiples and K to whole contraction
+    # tiles (exact: zero codes decode to 0; padded scales are 1.0 so no
+    # inf/nan can leak into 0 * sp).
+    kp = padded_k(K, k_block)
+    kt = k_tile(kp, k_block)
+    pm, pn, pk, pg = (-M) % block_m, (-N) % block_n, kp - K, (kp - K) // k_block
+    if pm or pk:
+        x_codes = jnp.pad(x_codes, ((0, pm), (0, pk)))
+    if pn or pk:
+        w_codes = jnp.pad(w_codes, ((0, pk), (0, pn)))
+    if grouping in ("nc", "n") and pm:
+        x_sg = jnp.pad(x_sg, ((0, pm), (0, 0)), constant_values=1.0)
+    if grouping in ("nc", "n") and pn:
+        w_sg = jnp.pad(w_sg, ((0, 0), (0, pn)), constant_values=1.0)
+    if grouping in ("nc", "c") and pg:
+        x_sg = jnp.pad(x_sg, ((0, 0), (0, pg)), constant_values=1.0)
+        w_sg = jnp.pad(w_sg, ((0, pg), (0, 0)), constant_values=1.0)
     Mp, Np = M + pm, N + pn
+    nkt, gpt = kp // kt, kt // k_block
 
     st = (x_st * w_st).astype(jnp.float32).reshape(1, 1)
-    xsg_spec, wsg_spec = _sg_specs(grouping, block_m, block_n)
-    kernel = functools.partial(_kernel, fmt=fmt, n_k=nkb)
+    x_sg, xsg_spec, x_scale = scale_operand(
+        x_sg, "x", grouping, nkt, gpt, block_m, grid_axis=0)
+    w_sg, wsg_spec, w_scale = scale_operand(
+        w_sg, "w", grouping, nkt, gpt, block_n, grid_axis=1)
+    kernel = functools.partial(
+        _kernel, fmt=fmt, n_k=nkt, k_block=k_block, x_scale=x_scale,
+        w_scale=w_scale)
     out = pl.pallas_call(
         kernel,
-        grid=(Mp // block_m, Np // block_n, nkb),
+        grid=(Mp // block_m, Np // block_n, nkt),
         in_specs=[
-            pl.BlockSpec((block_m, k_block), lambda i, j, k: (i, k)),
+            pl.BlockSpec((block_m, kt), lambda i, j, k: (i, k)),
             xsg_spec,
-            pl.BlockSpec((k_block, block_n), lambda i, j, k: (k, j)),
+            pl.BlockSpec((kt, block_n), lambda i, j, k: (k, j)),
             wsg_spec,
-            pl.BlockSpec((1, 1), lambda i, j, k: (0, 0)),
+            SMEM_SPEC,
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),

@@ -14,26 +14,34 @@ the kernel (a cheap fused max-reduce); it enters the kernel via SMEM.
 
 * ``"nc"`` — one group per (row, ``k_block``-wide contraction block);
   scales (M, K/k_block), computed inside the kernel (the default).
-* ``"n"``  — one group per row; scales (M, 1), computed inside the kernel
-  (a single full-width group per row block).
+* ``"n"``  — one group per row; scales (M, 1).  The row maximum spans every
+  contraction tile, so it is a fused XLA reduction ahead of the kernel.
 * ``"c"``  — one group per contraction block shared by *all* rows; scales
   (1, K/k_block).  The group max crosses row-block programs, so the compact
-  scales are precomputed by a fused XLA reduction (same exact
-  ``quantize_group_scale`` math) and the kernel only quantizes elements.
+  scales are precomputed by a fused XLA reduction.
 * ``"none"`` — tensor-wise only; group scales are exactly 1 (shape (1, 1)).
 
-Grid: one program per ``block_m`` rows; each program statically loops over
-the scaling groups of its rows, keeping the whole row block in VMEM.
+Scales, in the kernel or ahead of it, come from ``quantize_group_scale``
+itself, so every grouping is bit-identical to
+:func:`repro.kernels.ref.quantize_ref`.
+
+Grid: ``(M/block_m, K/k_tile)``.  The contraction axis is tiled
+(:func:`k_tile`), so VMEM holds one ``(block_m, k_tile)`` tile whatever K
+is — a weight-gradient operand contracts over ``N*OH*OW`` (131072 for a
+batch-128 CIFAR layer).  A tile holds a whole number of scaling groups,
+which the kernel walks with static lane slices.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.formats import EMFormat, GS_FMT_DEFAULT
+from repro.core.formats import EMFormat, GS_FMT_DEFAULT, exponent_fraction, pow2
 from repro.core.quantize import quantize_group_scale
 from .runtime import resolve_interpret
 
@@ -41,35 +49,58 @@ DEFAULT_BLOCK_M = 256
 
 GROUPINGS = ("nc", "c", "n", "none")
 
+_LANES = 128  # TPU vreg lane width: the minor dim of a VMEM tile
+_FULL_K_MAX = 2048  # contraction extents up to this stay one whole tile
 
-def _exponent_fraction(x):
-    """Bit-exact Exponent/Fraction on fp32 (kernel-local copy)."""
-    bits = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)
-    raw_exp = (bits >> 23) & 0xFF
-    man_bits = bits & 0x7FFFFF
-    bad = raw_exp == 0  # zero / fp32-subnormal -> treat as zero
-    e = jnp.where(bad, jnp.int32(-(2**30)), raw_exp - 127)
-    frac = jax.lax.bitcast_convert_type(man_bits | (127 << 23), jnp.int32)
-    frac = jnp.where(bad, 0.0, jax.lax.bitcast_convert_type(frac, jnp.float32))
-    return e, frac
+# Rounding offset of the round-to-nearest path: r_u8 = 127 in the
+# stochastic formula below, i.e. u = (127 + 0.5) / 256 - 0.5.
+_NEAREST = (127 + 0.5) / 256.0 - 0.5
+
+SMEM_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
-def _element_codes(x, r_u8, denom, fmt: EMFormat):
+def k_tile(K: int, k_block: int) -> int:
+    """Contraction tile of the quantize/GEMM kernels for a ``K`` that is a
+    multiple of ``k_block``: one scaling group when ``k_block`` is
+    lane-aligned, the whole ``K`` when it is short, else the smallest
+    lane-aligned whole number of groups (``lcm(k_block, 128)``)."""
+    if k_block % _LANES == 0:
+        return k_block
+    if K <= _FULL_K_MAX:
+        return K
+    return math.lcm(k_block, _LANES)
+
+
+def padded_k(K: int, k_block: int) -> int:
+    """``K`` rounded up to whole scaling groups and whole :func:`k_tile`
+    tiles.  Zero padding along K is exact for every grouping."""
+    K = -(-K // k_block) * k_block
+    kt = k_tile(K, k_block)
+    return -(-K // kt) * kt
+
+
+def rounding_offset(r_u8):
+    """uint8 stochastic-rounding draws -> offsets u in (-1/2, 1/2).  The
+    cast goes through int32: Mosaic has no direct uint8 -> fp32 convert."""
+    return (r_u8.astype(jnp.int32).astype(jnp.float32) + 0.5) / 256.0 - 0.5
+
+
+def _element_codes(x, r, denom, fmt: EMFormat):
     """Packed codes for one block given its scale denominator (Alg. 2
-    l.9-16).  ``denom`` broadcasts against ``x`` ((bm, 1), (1,) or scalar)."""
+    l.9-16).  ``denom`` broadcasts against ``x`` ((bm, 1), (1,) or scalar);
+    ``r`` is a :func:`rounding_offset` array, or ``None`` for nearest."""
     absx = jnp.abs(x)
     sign_bit = (x < 0).astype(jnp.int32)
     x_f = jnp.where(denom > 0, absx / jnp.where(denom > 0, denom, 1.0), 0.0)
-    e_x, _ = _exponent_fraction(x_f)
+    e_x, _ = exponent_fraction(x_f)
     e_eff = jnp.clip(e_x, fmt.e_min, -1)
-    step = jnp.exp2((e_eff - fmt.m).astype(jnp.float32))
-    r = (r_u8.astype(jnp.float32) + 0.5) / 256.0 - 0.5
-    q = jnp.floor(x_f / step + r + 0.5)
+    step = pow2(e_eff - fmt.m)
+    q = jnp.floor(x_f / step + (_NEAREST if r is None else r) + 0.5)
     qmax = jnp.where(e_eff == -1, 2.0 ** (fmt.m + 1) - 1.0, 2.0 ** (fmt.m + 1))
     q = jnp.clip(q, 0.0, qmax)
     xbar = q * step
 
-    e2, frac2 = _exponent_fraction(xbar)
+    e2, frac2 = exponent_fraction(xbar)
     is_normal = e2 >= fmt.e_min
     man = jnp.where(
         is_normal,
@@ -82,58 +113,46 @@ def _element_codes(x, r_u8, denom, fmt: EMFormat):
     ).astype(jnp.uint8)
 
 
-def _quantize_block(x, r_u8, s_t, fmt: EMFormat, gs_fmt: EMFormat):
-    """Quantize one (block_m, group_width) group column -> (codes, s_g)."""
-    absx = jnp.abs(x)
-
-    # ---- group scale (one per row of the block), Alg. 2 l.2-8 ------------
-    s_r = jnp.max(absx, axis=1, keepdims=True)  # (bm, 1)
-    s_gf = s_r / s_t
-    eg_min = max(gs_fmt.e_min, -120)
-    e_g, frac_g = _exponent_fraction(s_gf)
-    too_small = e_g < eg_min
-    e_g = jnp.clip(e_g, eg_min, 0)
-    frac_g = jnp.where(too_small, 1.0, frac_g)
-    man_g = jnp.ceil((frac_g - 1.0) * 2.0**gs_fmt.m)
-    overflow = man_g >= 2**gs_fmt.m
-    man_g = jnp.where(overflow, 0.0, man_g)
-    e_g = jnp.clip(jnp.where(overflow, e_g + 1, e_g), eg_min, 0)
-    s_g = (1.0 + man_g * 2.0**-gs_fmt.m) * jnp.exp2(e_g.astype(jnp.float32))
-
-    codes = _element_codes(x, r_u8, s_t * s_g, fmt)
-    return codes, s_g[:, 0]
+def _quantize_block(x, r, s_t, fmt: EMFormat, gs_fmt: EMFormat):
+    """Quantize one (block_m, group_width) group column -> (codes, s_g
+    (block_m, 1))."""
+    s_r = jnp.max(jnp.abs(x), axis=1, keepdims=True)
+    s_g, _, _ = quantize_group_scale(s_r / s_t, gs_fmt)
+    return _element_codes(x, r, s_t * s_g, fmt), s_g
 
 
-def _kernel_rowwise(
-    x_ref, r_ref, st_ref, codes_ref, sg_ref, *, fmt, gs_fmt, group_width
-):
-    """In-kernel group scales: ``"nc"`` (group_width == k_block) and
-    ``"n"`` (group_width == K: one group per row)."""
+def _kernel(*refs, fmt, gs_fmt, k_block, grouping, stochastic):
+    """Quantize one ``(block_m, k_tile)`` tile.  ``"nc"`` computes its
+    group scales here and writes them to a ``(1, block_m, groups)`` block;
+    the other groupings read precomputed scales (``"n"``: a (block_m, 1)
+    VMEM block; ``"c"``/``"none"``: a flat SMEM array)."""
+    it = iter(refs)
+    x_ref = next(it)
+    r_ref = next(it) if stochastic else None
+    st_ref = next(it)
+    sg_in = None if grouping == "nc" else next(it)
+    codes_ref = next(it)
+    sg_out = next(it) if grouping == "nc" else None
     s_t = st_ref[0, 0]
-    n_groups = x_ref.shape[1] // group_width
-    for g in range(n_groups):  # static loop over scaling groups
-        sl = pl.dslice(g * group_width, group_width)
-        codes, s_g = _quantize_block(
-            x_ref[:, sl], r_ref[:, sl], s_t, fmt, gs_fmt
-        )
+    x = x_ref[...]
+    r = rounding_offset(r_ref[...]) if stochastic else None
+
+    if grouping in ("n", "none"):  # one scale for the whole tile row
+        s_g = sg_in[...] if grouping == "n" else sg_in[0]
+        codes_ref[...] = _element_codes(x, r, s_t * s_g, fmt)
+        return
+
+    k = pl.program_id(1)
+    gpt = x.shape[1] // k_block
+    for g in range(gpt):  # static loop over the tile's scaling groups
+        sl = slice(g * k_block, (g + 1) * k_block)
+        rg = None if r is None else r[:, sl]
+        if grouping == "nc":
+            codes, s_g = _quantize_block(x[:, sl], rg, s_t, fmt, gs_fmt)
+            sg_out[0, :, g:g + 1] = s_g
+        else:  # "c"
+            codes = _element_codes(x[:, sl], rg, s_t * sg_in[k * gpt + g], fmt)
         codes_ref[:, sl] = codes
-        sg_ref[:, pl.dslice(g, 1)] = s_g[:, None]
-
-
-def _kernel_given_sg(
-    x_ref, r_ref, st_ref, sg_ref, codes_ref, *, fmt, k_block
-):
-    """Element quantization against precomputed compact scales (``"c"``:
-    sg (1, K/k_block); ``"none"``: sg (1, 1) == 1)."""
-    s_t = st_ref[0, 0]
-    n_groups = x_ref.shape[1] // k_block
-    per_group = sg_ref.shape[1] > 1
-    for g in range(n_groups):
-        sl = pl.dslice(g * k_block, k_block)
-        s_g = sg_ref[0, g] if per_group else sg_ref[0, 0]
-        codes_ref[:, sl] = _element_codes(
-            x_ref[:, sl], r_ref[:, sl], s_t * s_g, fmt
-        )
 
 
 def mls_quantize_pallas(
@@ -155,7 +174,8 @@ def mls_quantize_pallas(
     A ragged row count (``M`` not a multiple of the clamped ``block_m``) is
     zero-padded and sliced back — exact: zero rows quantize to zero codes
     and never contribute to any cross-row group maximum.  ``K`` must be a
-    multiple of ``k_block`` (group boundaries), else ``ValueError``.
+    multiple of ``k_block`` (group boundaries), else ``ValueError``; it is
+    zero-padded to whole contraction tiles the same way.
     """
     if grouping not in GROUPINGS:
         raise ValueError(
@@ -172,67 +192,68 @@ def mls_quantize_pallas(
     x = x.astype(jnp.float32)
     s_t = jnp.max(jnp.abs(x))
     s_t = jnp.where(s_t > 0, s_t, 1.0).reshape(1, 1)
-    if key is not None:
+    stochastic = key is not None
+    r_u8 = None
+    if stochastic:
         r_u8 = jax.random.randint(key, x.shape, 0, 256, dtype=jnp.int32).astype(
             jnp.uint8
         )
-    else:
-        r_u8 = jnp.full(x.shape, 127, dtype=jnp.uint8)  # r = -0.002 ~ nearest
 
-    pm = (-M) % block_m
-    if pm:
-        x = jnp.pad(x, ((0, pm), (0, 0)))
-        r_u8 = jnp.pad(r_u8, ((0, pm), (0, 0)), constant_values=127)
+    kp = padded_k(K, k_block)
+    kt = k_tile(kp, k_block)
+    pm, pk = (-M) % block_m, kp - K
+    if pm or pk:
+        x = jnp.pad(x, ((0, pm), (0, pk)))
+        if stochastic:
+            r_u8 = jnp.pad(r_u8, ((0, pm), (0, pk)), constant_values=127)
     Mp = M + pm
-    nkb = K // k_block
+    nkb, nkt, gpt = kp // k_block, kp // kt, kt // k_block
 
-    if grouping in ("nc", "n"):
-        group_width = k_block if grouping == "nc" else K
-        n_sg = nkb if grouping == "nc" else 1
-        kernel = functools.partial(
-            _kernel_rowwise, fmt=fmt, gs_fmt=gs_fmt, group_width=group_width)
-        codes, s_g = pl.pallas_call(
-            kernel,
-            grid=(Mp // block_m,),
-            in_specs=[
-                pl.BlockSpec((block_m, K), lambda i: (i, 0)),
-                pl.BlockSpec((block_m, K), lambda i: (i, 0)),
-                pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((block_m, K), lambda i: (i, 0)),
-                pl.BlockSpec((block_m, n_sg), lambda i: (i, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((Mp, K), jnp.uint8),
-                jax.ShapeDtypeStruct((Mp, n_sg), jnp.float32),
-            ],
-            interpret=interpret,
-        )(x, r_u8, s_t)
-        if pm:
-            codes, s_g = codes[:M], s_g[:M]
-        return codes, s_g, s_t[0, 0]
+    tile = pl.BlockSpec((block_m, kt), lambda i, k: (i, k))
+    in_specs = [tile] + ([tile] if stochastic else []) + [SMEM_SPEC]
+    operands = [x] + ([r_u8] if stochastic else []) + [s_t]
+    out_specs = [tile]
+    out_shape = [jax.ShapeDtypeStruct((Mp, kp), jnp.uint8)]
+    if grouping == "nc":
+        out_specs.append(
+            pl.BlockSpec((1, block_m, gpt), lambda i, k: (k, i, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((nkt, Mp, gpt), jnp.float32))
+    else:
+        # compact scales precomputed: the "n" row max spans every k-tile,
+        # the "c" group max every row block.
+        if grouping == "n":
+            s_r = jnp.max(jnp.abs(x), axis=1, keepdims=True)
+            s_g, _, _ = quantize_group_scale(s_r / s_t[0, 0], gs_fmt)
+            in_specs.append(pl.BlockSpec((block_m, 1), lambda i, k: (i, 0)))
+            operands.append(s_g)  # (Mp, 1)
+        elif grouping == "c":
+            s_r = jnp.max(jnp.abs(x), axis=0).reshape(nkb, k_block).max(axis=1)
+            s_g, _, _ = quantize_group_scale(s_r / s_t[0, 0], gs_fmt)
+            in_specs.append(SMEM_SPEC)
+            operands.append(s_g)  # (nkb,)
+        else:  # "none"
+            s_g = jnp.ones((1,), jnp.float32)
+            in_specs.append(SMEM_SPEC)
+            operands.append(s_g)
 
-    # "c" / "none": compact scales precomputed (exact quantize_group_scale
-    # math; for "c" the group max crosses row-block programs).
-    if grouping == "c":
-        s_r = jnp.max(jnp.abs(x), axis=0).reshape(1, nkb, k_block).max(axis=2)
-        s_g, _, _ = quantize_group_scale(s_r / s_t[0, 0], gs_fmt)  # (1, nkb)
-    else:  # "none"
-        s_g = jnp.ones((1, 1), jnp.float32)
-    n_sg = s_g.shape[1]
-    kernel = functools.partial(_kernel_given_sg, fmt=fmt, k_block=k_block)
-    codes = pl.pallas_call(
+    kernel = functools.partial(
+        _kernel, fmt=fmt, gs_fmt=gs_fmt, k_block=k_block, grouping=grouping,
+        stochastic=stochastic)
+    res = pl.pallas_call(
         kernel,
-        grid=(Mp // block_m,),
-        in_specs=[
-            pl.BlockSpec((block_m, K), lambda i: (i, 0)),
-            pl.BlockSpec((block_m, K), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, n_sg), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_m, K), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((Mp, K), jnp.uint8),
+        grid=(Mp // block_m, nkt),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
         interpret=interpret,
-    )(x, r_u8, s_t, s_g)
-    return (codes[:M] if pm else codes), s_g, s_t[0, 0]
+    )(*operands)
+    codes = res[0][:M, :K]
+    if grouping == "nc":
+        s_g = res[1].transpose(1, 0, 2).reshape(Mp, nkb)[:M, : K // k_block]
+    elif grouping == "n":
+        s_g = s_g[:M]
+    elif grouping == "c":
+        s_g = s_g[: K // k_block].reshape(1, -1)
+    else:
+        s_g = s_g.reshape(1, 1)
+    return codes, s_g, s_t[0, 0]

@@ -120,7 +120,8 @@ def mls_matmul_ref(
     layouts exactly.
     Intra-group: integer MAC over each k-block (exact in fp32).
     Inter-group: group-scale product (a shift-add in hardware, exact fp32
-    multiply here) then fp32 accumulation — the paper's adder tree.
+    multiply here) then fp32 accumulation in group order — the paper's
+    adder tree.
     """
     M, K = x_codes.shape
     K2, N = w_codes.shape
@@ -132,10 +133,16 @@ def mls_matmul_ref(
     fw = decode_frac_int(w_codes, fmt).astype(jnp.float32)
     fx = fx.reshape(M, nkb, k_block)
     fw = fw.reshape(nkb, k_block, N)
-    # intra-group integer MACs: P[m, g, n]
-    p = jnp.einsum("mgk,gkn->gmn", fx, fw)
-    # inter-group: scale by S_p = s_g^x * s_g^w and accumulate
+    # intra-group integer MACs: P[g, m, n] (exact at any precision that
+    # keeps fp32 products; HIGHEST so a TPU does not round operands to bf16)
+    p = jnp.einsum("mgk,gkn->gmn", fx, fw, precision=jax.lax.Precision.HIGHEST)
+    # inter-group: scale by S_p = s_g^x * s_g^w and accumulate group by
+    # group in contraction order — the kernel's order, on every backend
     sp = x_sg.T[:, :, None] * w_sg[:, None, :]  # (g, M, N)
-    z = jnp.sum(p * sp, axis=0)
+
+    def add_group(z, ps):
+        return z + ps[0] * ps[1], None
+
+    z, _ = jax.lax.scan(add_group, jnp.zeros((M, N), jnp.float32), (p, sp))
     unit = 2.0 ** (2 * (fmt.e_min - fmt.m))
     return z * (x_st * w_st * unit)

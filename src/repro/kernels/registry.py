@@ -16,6 +16,10 @@ One table, three consumers:
 Entries build *abstract* example arguments (``jax.ShapeDtypeStruct``), so
 registering and tracing a kernel never allocates or executes anything;
 ``concrete_args`` materializes random inputs only when a benchmark asks.
+The Pallas execution mode is the caller's: ``build(interpret)`` forwards
+it to the kernels, where ``None`` resolves through
+:mod:`repro.kernels.runtime` (Mosaic on a TPU).  The static verifier traces
+with ``interpret=True``.
 """
 from __future__ import annotations
 
@@ -41,8 +45,8 @@ __all__ = ["KERNEL_REGISTRY", "KernelEntry"]
 class KernelEntry:
     """One verifiable/benchable Pallas kernel entry point.
 
-    ``build()`` returns ``(fn, abstract_args)`` — a traceable callable and
-    example ``ShapeDtypeStruct`` arguments.  ``needs_grad`` marks training
+    ``build(interpret)`` returns ``(fn, abstract_args)`` — a traceable
+    callable and example ``ShapeDtypeStruct`` arguments.  ``needs_grad`` marks training
     ops whose custom-VJP backward GEMMs must be verified too (the verifier
     traces ``jax.vjp`` through them).  ``bench_tag`` names the example
     shape in benchmark rows (kept stable for the perf trail).  ``tune`` is
@@ -53,18 +57,20 @@ class KernelEntry:
 
     name: str
     description: str
-    build: Callable[[], tuple[Callable, tuple]]
+    build: Callable[[bool | None], tuple[Callable, tuple]]
     needs_grad: bool = False
     bench: bool = True
     bench_tag: str = ""
     tune: TuneSpec | None = None
 
-    def fn_and_args(self) -> tuple[Callable, tuple]:
-        return self.build()
+    def fn_and_args(
+        self, interpret: bool | None = None
+    ) -> tuple[Callable, tuple]:
+        return self.build(interpret)
 
-    def trace(self):
+    def trace(self, interpret: bool | None = True):
         """ClosedJaxpr of the forward (+ backward when ``needs_grad``)."""
-        fn, avals = self.build()
+        fn, avals = self.build(interpret)
         if self.needs_grad:
             def fwd_bwd(*args):
                 y, vjp = jax.vjp(fn, *args)
@@ -74,7 +80,7 @@ class KernelEntry:
 
     def concrete_args(self, seed: int = 0) -> tuple:
         """Random concrete inputs matching the example abstract shapes."""
-        _, avals = self.build()
+        _, avals = self.build(None)
         keys = jax.random.split(jax.random.key(seed), max(len(avals), 2))
         out = []
         for k, a in zip(keys, avals):
@@ -91,19 +97,19 @@ class KernelEntry:
 _F32 = jnp.float32
 
 
-def _build_quantize():
+def _build_quantize(interpret):
     def fn(x):
-        return mls_quantize_pallas(x, FMT_IMAGENET, 128, interpret=True)
+        return mls_quantize_pallas(x, FMT_IMAGENET, 128, interpret=interpret)
     return fn, (jax.ShapeDtypeStruct((256, 512), _F32),)
 
 
-def _build_matmul():
+def _build_matmul(interpret):
     kb, M, K, N = 128, 256, 512, 256
 
     def fn(xc, xsg, xst, wc, wsg, wst):
         return mls_matmul_pallas(
             xc, xsg, xst, wc, wsg, wst, FMT_IMAGENET,
-            k_block=kb, block_m=128, block_n=128, interpret=True,
+            k_block=kb, block_m=128, block_n=128, interpret=interpret,
         )
     avals = (
         jax.ShapeDtypeStruct((M, K), jnp.uint8),
@@ -116,21 +122,21 @@ def _build_matmul():
     return fn, avals
 
 
-def _build_matmul_fused():
+def _build_matmul_fused(interpret):
     def fn(x, w):
         return lowbit_matmul_fused(x, w, None, fmt=FMT_IMAGENET,
-                                   interpret=True)
+                                   interpret=interpret)
     return fn, (jax.ShapeDtypeStruct((256, 512), _F32),
                 jax.ShapeDtypeStruct((512, 256), _F32))
 
 
-def _conv_cfg() -> QuantConfig:
+def _conv_cfg(interpret) -> QuantConfig:
     return QuantConfig(fmt=FMT_IMAGENET, stochastic=False, backend="pallas",
-                       k_block=32, pallas_interpret=True)
+                       k_block=32, pallas_interpret=interpret)
 
 
-def _build_conv_fused():
-    cfg = _conv_cfg()
+def _build_conv_fused(interpret):
+    cfg = _conv_cfg(interpret)
 
     def fn(x, w):
         return lowbit_conv_fused(x, w, None, (1, 1), "SAME", cfg)
@@ -138,15 +144,15 @@ def _build_conv_fused():
                 jax.ShapeDtypeStruct((16, 16, 3, 3), _F32))
 
 
-def _implicit_conv_cfg() -> QuantConfig:
+def _implicit_conv_cfg(interpret) -> QuantConfig:
     # k_block = cb*kh*kw = 4*3*3: legal implicit grouping for C=16 3x3 convs
     return QuantConfig(fmt=FMT_IMAGENET, stochastic=False, backend="pallas",
                        k_block=36, conv_impl="implicit",
-                       pallas_interpret=True)
+                       pallas_interpret=interpret)
 
 
-def _build_conv_implicit():
-    cfg = _implicit_conv_cfg()
+def _build_conv_implicit(interpret):
+    cfg = _implicit_conv_cfg(interpret)
 
     def fn(x, w):
         return lowbit_conv_fused(x, w, None, (1, 1), "SAME", cfg)
@@ -157,8 +163,8 @@ def _build_conv_implicit():
 _ICONV_GEOM = conv_geometry((2, 16, 8, 8), (16, 16, 3, 3), (1, 1), "SAME")
 
 
-def _build_matmul_qd():
-    cfg = _conv_cfg()
+def _build_matmul_qd(interpret):
+    cfg = _conv_cfg(interpret)
 
     def fn(x, w):
         return lowbit_matmul_qd(x, w, None, cfg)

@@ -16,12 +16,8 @@ import jax
 
 
 def make_mesh(shape, axes) -> jax.sharding.Mesh:
-    # jax >= 0.5 grew explicit axis types; Auto matches the implicit
-    # behaviour of older releases, so omit the kwarg when it doesn't exist.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=auto)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

@@ -28,7 +28,12 @@ from repro.launch.specs import choose_microbatch
 from repro.models import lm
 from repro.parallel import DEFAULT_RULES, axis_rules
 from repro.parallel.specs import batch_shardings, param_shardings
-from repro.train import CheckpointManager, StragglerMonitor, make_train_step
+from repro.train import (
+    CheckpointManager,
+    StragglerMonitor,
+    enable_compile_cache,
+    make_train_step,
+)
 
 
 def main():
@@ -44,6 +49,7 @@ def main():
     ap.add_argument("--batch", type=int, default=0, help="override batch")
     ap.add_argument("--seq", type=int, default=0, help="override seq")
     args = ap.parse_args()
+    enable_compile_cache()
 
     shape = SHAPES[args.shape]
     if args.smoke:

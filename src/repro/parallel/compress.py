@@ -55,12 +55,7 @@ def crosspod_allreduce_mean(g: jax.Array, axis_name: str = "pod",
     Must run inside ``shard_map`` with ``axis_name`` bound.  Exact wire
     payload per hop: 1 B/elem codes + 4 B/128-elem group scales.
     """
-    # jax < 0.6 has no lax.axis_size; psum of a literal 1 is the classic
-    # idiom and stays static (resolved from the axis env at trace time)
-    axis_size = getattr(jax.lax, "axis_size", None)
-    p = int(axis_size(axis_name)) if axis_size is not None else int(
-        jax.lax.psum(1, axis_name)
-    )
+    p = int(jax.lax.axis_size(axis_name))
     if p == 1:
         return g
     codes, s_g, s_t = compress(g, fmt, key=key)

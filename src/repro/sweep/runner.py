@@ -22,8 +22,9 @@ from repro.configs import get_smoke_config
 from repro.core import QuantConfig
 from repro.data import make_cifar_iterator, make_lm_iterator
 from repro.models import lm
-from repro.models.cnn import CNNConfig, apply_cnn, init_cnn
-from repro.optim import adamw_init, adamw_update, sgdm_init, sgdm_update
+from repro.models.cnn import CNNConfig, init_cnn
+from repro.optim import adamw_init, adamw_update, sgdm_init
+from repro.train import make_cnn_train_step
 
 from .grid import LM_ARCHS, Cell
 
@@ -54,19 +55,7 @@ def _train_cnn(cell: Cell) -> tuple[float, float | None]:
     nxt, ds = make_cifar_iterator(batch=cell.batch, hw=cell.hw,
                                   num_classes=_NUM_CLASSES, seed=cell.seed)
 
-    @jax.jit
-    def step(params, opt, batch, i):
-        def loss_fn(p):
-            logits = apply_cnn(p, batch["image"], cfg, qcfg,
-                               jax.random.fold_in(jax.random.key(1), i))
-            ll = jax.nn.log_softmax(logits)
-            loss = -jnp.take_along_axis(ll, batch["label"][:, None], 1).mean()
-            acc = (logits.argmax(-1) == batch["label"]).mean()
-            return loss, acc
-
-        (l, a), g = jax.value_and_grad(loss_fn, has_aux=True)(params)
-        params, opt = sgdm_update(g, opt, params, lr=cell.lr)
-        return params, opt, l, a
+    step = jax.jit(make_cnn_train_step(cfg, qcfg, cell.lr, rounding_seed=1))
 
     losses, accs = [], []
     for i in range(cell.steps):

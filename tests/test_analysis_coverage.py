@@ -8,6 +8,7 @@ import jax.numpy as jnp
 
 from repro.analysis.audit import _BASELINE, apply_gate
 from repro.analysis.coverage import coverage_of_jaxpr, trace_coverage
+from repro.analysis.kernel_verify import find_pallas_eqns
 from repro.analysis.graphs import cifar_train_graph
 from repro.core import FMT_IMAGENET, QuantConfig
 from repro.kernels.lowbit_conv import lowbit_conv_fused, lowbit_matmul_qd
@@ -25,16 +26,20 @@ def test_pallas_matmul_grad_fully_quantized():
     def loss(x, w):
         return lowbit_matmul_qd(x, w, None, cfg).sum()
 
-    rep = trace_coverage(
-        jax.grad(loss, argnums=(0, 1)),
-        jax.ShapeDtypeStruct((64, 96), jnp.float32),
-        jax.ShapeDtypeStruct((96, 128), jnp.float32),
-    )
+    args = (jax.ShapeDtypeStruct((64, 96), jnp.float32),
+            jax.ShapeDtypeStruct((96, 128), jnp.float32))
+    rep = trace_coverage(jax.grad(loss, argnums=(0, 1)), *args)
     assert rep.quantized_macs > 0
     assert rep.full_precision_macs == 0
     assert rep.quantized_fraction == 1.0
-    # all three training GEMMs (fwd, dgrad, wgrad) visible
-    assert sum(1 for s in rep.sites if s.klass == "quantized") == 3
+    # all three training GEMMs (fwd, dgrad, wgrad) visible: three GEMM
+    # kernels, each contracting its scaling groups with quantized dots
+    cj = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(*args)
+    gemms = [e for e in find_pallas_eqns(cj.jaxpr)
+             if any(q.primitive.name == "dot_general"
+                    for q in e.params["jaxpr"].eqns)]
+    assert len(gemms) == 3
+    assert all(s.klass == "quantized" for s in rep.sites if s.kind == "dot")
 
 
 def test_fake_quant_backend_is_full_precision():

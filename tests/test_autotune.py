@@ -216,3 +216,28 @@ def test_interpret_arg_defers_to_env(monkeypatch):
     assert runtime.resolve_interpret(None) is False
     monkeypatch.setenv(runtime.INTERPRET_ENV_VAR, "yes")
     assert runtime.resolve_interpret(None) is True
+
+
+# ---------------------------------------------------------------------------
+# resolution reads committed files only, plus an explicit overlay
+# ---------------------------------------------------------------------------
+def test_resolution_ignores_uncommitted_local_cache(tmp_path, monkeypatch):
+    """A stray ``.cache/kernel_tune.json`` in the working directory never
+    changes resolution; ``REPRO_TUNE_CACHE`` overlays it explicitly."""
+    from repro.kernels import autotune
+
+    local = TuneCache(tmp_path / ".cache" / "kernel_tune.json")
+    local.put(SPEC, BlockConfig(32, 32, 32), us=1.0)
+    local.save()
+    monkeypatch.chdir(tmp_path)
+    monkeypatch.delenv(autotune.CACHE_ENV_VAR, raising=False)
+    autotune.invalidate_cache()
+    try:
+        seed = TuneCache.load(SEED_CACHE_PATH)
+        assert autotune.get_cache().entries == seed.entries
+        assert autotune.get_cache().get(SPEC.key()) is None
+        monkeypatch.setenv(autotune.CACHE_ENV_VAR, str(local.path))
+        autotune.invalidate_cache()
+        assert autotune.get_cache().get(SPEC.key()) == BlockConfig(32, 32, 32)
+    finally:
+        autotune.invalidate_cache()

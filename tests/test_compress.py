@@ -39,14 +39,10 @@ from jax.sharding import PartitionSpec as P
 from repro.launch.mesh import make_mesh
 from repro.parallel.compress import crosspod_allreduce_mean
 
-shard_map = getattr(jax, "shard_map", None)
-if shard_map is None:  # jax < 0.6 keeps it in experimental
-    from jax.experimental.shard_map import shard_map
-
 mesh = make_mesh((2, 2), ("pod", "data"))
 g = jax.random.normal(jax.random.key(0), (4, 256))
 
-@partial(shard_map, mesh=mesh, in_specs=P("pod", None),
+@partial(jax.shard_map, mesh=mesh, in_specs=P("pod", None),
          out_specs=P("pod", None))
 def f(x):
     return crosspod_allreduce_mean(x, "pod")[None] if x.ndim == 1 else \

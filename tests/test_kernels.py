@@ -1,6 +1,7 @@
 """Pallas kernel vs pure-jnp oracle: shape/dtype sweeps, bit-exactness."""
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 import numpy as np
 import pytest
 
@@ -127,13 +128,17 @@ def test_grouping_changes_executed_scale_layout():
         gm = gemm_eqn.params["grid_mapping"]
         # operands: x_codes, x_sg, w_codes, w_sg, st
         return tuple(
-            tuple(int(b) for b in gm.block_mappings[i].block_shape)
+            tuple(1 if isinstance(b, pl.Squeezed) else b.block_size
+                  for b in gm.block_mappings[i].block_shape)
             for i in (1, 3))
 
-    assert sg_block_shapes("nc") == ((64, 1), (1, 64))
-    assert sg_block_shapes("c") == ((1, 1), (1, 1))
+    # K=256 is one contraction tile of four 64-wide groups: "nc" scales
+    # arrive as (tile, rows, groups) / (tile, groups, cols) blocks, "n" as
+    # row / column blocks, "c" and "none" as whole SMEM arrays of scalars.
+    assert sg_block_shapes("nc") == ((1, 64, 4), (1, 4, 64))
+    assert sg_block_shapes("c") == ((4,), (4,))
     assert sg_block_shapes("n") == ((64, 1), (1, 64))
-    assert sg_block_shapes("none") == ((1, 1), (1, 1))
+    assert sg_block_shapes("none") == ((1,), (1,))
     # "n" delivers the same block shape as "nc" but from a (M, 1) array —
     # the full-array layouts must differ
     def sg_array_shapes(grouping):

@@ -91,3 +91,68 @@ def test_straggler_monitor_flags_slow_steps():
         mon.stop()
     rep = mon.report()
     assert 5 in rep["straggler_steps"], rep
+
+
+# ---------------------------------------------------------------------------
+# persistent compilation cache: one fixed directory
+# ---------------------------------------------------------------------------
+def test_compile_cache_defaults_to_fixed_repo_dir(monkeypatch):
+    import pathlib
+
+    from repro.train import compile_cache, enable_compile_cache
+
+    monkeypatch.delenv(compile_cache.CACHE_DIR_ENV_VAR, raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        got = enable_compile_cache()
+        repo = pathlib.Path(__file__).resolve().parents[1]
+        assert got == str(repo / ".cache" / "jax")
+        assert jax.config.jax_compilation_cache_dir == got
+        assert enable_compile_cache() == got  # the same path on every run
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_compile_cache_env_dir_wins(monkeypatch, tmp_path):
+    from repro.train import compile_cache, enable_compile_cache
+
+    monkeypatch.setenv(compile_cache.CACHE_DIR_ENV_VAR, str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert enable_compile_cache() == str(tmp_path)
+    # JAX reads the variable itself; no other directory is set in code
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+# ---------------------------------------------------------------------------
+# the shared CNN train step
+# ---------------------------------------------------------------------------
+def test_cnn_train_step_loss_and_schedule():
+    """The builder's loss is the softmax cross-entropy of ``apply_cnn`` and
+    a schedule is read at the optimizer's step count."""
+    from repro.data import make_cifar_iterator
+    from repro.models.cnn import CNNConfig, apply_cnn, init_cnn
+    from repro.optim import sgdm_init
+    from repro.train import make_cnn_train_step
+
+    cfg = CNNConfig(arch="resnet20", width_mult=0.25, in_hw=8)
+    params = init_cnn(jax.random.key(0), cfg)
+    opt = sgdm_init(params)
+    batch, _ = make_cifar_iterator(batch=4, hw=8)[0](
+        make_cifar_iterator(batch=4, hw=8)[1])
+    seen = []
+
+    def lr_fn(count):
+        seen.append(count)
+        return 0.05
+
+    step = jax.jit(make_cnn_train_step(cfg, None, lr_fn, rounding_seed=3))
+    new_params, new_opt, loss, acc = step(params, opt, batch, jnp.int32(0))
+    logits = apply_cnn(params, batch["image"], cfg, None)
+    ll = jax.nn.log_softmax(logits)
+    want = -jnp.take_along_axis(ll, batch["label"][:, None], 1).mean()
+    np.testing.assert_allclose(float(loss), float(want), rtol=1e-5)
+    assert 0.0 <= float(acc) <= 1.0
+    assert seen and int(new_opt.step) == int(opt.step) + 1
+    moved = jax.tree.leaves(jax.tree.map(
+        lambda a, b: bool(jnp.any(a != b)), params, new_params))
+    assert any(moved)
