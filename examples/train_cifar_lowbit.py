@@ -42,8 +42,8 @@ def train(variant, qcfg, args, ckpt_dir=None):
         batch, ds = nxt(ds)
         mon.start()
         params, opt, l, a = step(params, opt, batch, jnp.int32(i))
+        accs.append(float(a))  # waits for the step: the monitor times it
         dt = mon.stop()
-        accs.append(float(a))
         if mgr and (i + 1) % 50 == 0:
             mgr.save(i + 1, {"params": params, "opt": opt, "data": ds},
                      blocking=False)

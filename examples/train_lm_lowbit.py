@@ -59,6 +59,7 @@ def main():
             batch, ds = nxt(ds)
             mon.start()
             params, opt, m = step(params, opt, batch)
+            jax.block_until_ready(m["loss"])
             dt = mon.stop()
             if (i + 1) % max(args.steps // 10, 1) == 0:
                 print(f"  step {i+1}: loss={float(m['loss']):.3f} "

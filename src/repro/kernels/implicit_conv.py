@@ -503,12 +503,14 @@ def implicit_conv_forward(
     bm = bh * geom.ow
     interpret = resolve_interpret(interpret)
 
-    xp = jnp.pad(
-        x.astype(jnp.float32),
-        ((0, 0), (0, 0), (geom.ph_lo, geom.ph_hi), (geom.pw_lo, geom.pw_hi)),
-    )
-    s_t, x_sg = _implicit_x_scales(xp, geom, fmt, gs_fmt, kb, grouping)
-    stx = s_t.reshape(1, 1)
+    with jax.named_scope("im2col"):
+        xp = jnp.pad(
+            x.astype(jnp.float32),
+            ((0, 0), (0, 0), (geom.ph_lo, geom.ph_hi), (geom.pw_lo, geom.pw_hi)),
+        )
+    with jax.named_scope("quantize"):
+        s_t, x_sg = _implicit_x_scales(xp, geom, fmt, gs_fmt, kb, grouping)
+        stx = s_t.reshape(1, 1)
 
     # Weight side: byte-for-byte the im2col pipeline's (see qd_gemm) — the
     # OIHW weight flattens to (K0, O), is padded to the N-tile, and is
@@ -518,17 +520,18 @@ def implicit_conv_forward(
     # O pads to the *unclamped* block_n multiple — exactly qd_gemm's
     # _pad_to — so the weight-side stochastic draws are shape-identical to
     # the im2col/ref pipeline; the kernel's N-tile clamps separately below.
-    wmat = w.reshape(geom.o, -1).T.astype(jnp.float32)  # (K0, O)
     pn = (-geom.o) % block_n
-    wp = jnp.pad(wmat, ((0, 0), (0, pn))) if pn else wmat
     op = geom.o + pn
     bn = min(block_n, op)
-    wc, wsgT, wst = mls_quantize_pallas(
-        wp.T, fmt, kb, gs_fmt, key_w, block_m=block_n, interpret=interpret,
-        grouping=grouping,
-    )
-    wsg = wsgT.T
-    stp = (s_t * wst).astype(jnp.float32).reshape(1, 1)
+    with jax.named_scope("quantize"):
+        wmat = w.reshape(geom.o, -1).T.astype(jnp.float32)  # (K0, O)
+        wp = jnp.pad(wmat, ((0, 0), (0, pn))) if pn else wmat
+        wc, wsgT, wst = mls_quantize_pallas(
+            wp.T, fmt, kb, gs_fmt, key_w, block_m=block_n, interpret=interpret,
+            grouping=grouping,
+        )
+        wsg = wsgT.T
+        stp = (s_t * wst).astype(jnp.float32).reshape(1, 1)
 
     stochastic = key_x is not None
     oh_tiles = geom.oh // bh
@@ -536,7 +539,8 @@ def implicit_conv_forward(
     grid = (geom.m0 // bm, op // bn, n_k)
 
     # Channel-blocked channels-last activation: (N, C/cb, Hp, Wp, cb).
-    x5 = xp.reshape(geom.n, n_k, cb, geom.hp, geom.wp).transpose(0, 1, 3, 4, 2)
+    with jax.named_scope("im2col"):
+        x5 = xp.reshape(geom.n, n_k, cb, geom.hp, geom.wp).transpose(0, 1, 3, 4, 2)
     in_specs = [
         pl.BlockSpec((1, n_k, geom.hp, geom.wp, cb),
                      lambda i, j, k, t=oh_tiles: (i // t, 0, 0, 0, 0)),
@@ -548,12 +552,14 @@ def implicit_conv_forward(
         return a.reshape(a.shape[0], n_k, cb, kk).transpose(1, 3, 0, 2)
 
     if stochastic:
-        r_u8 = jax.random.randint(
-            key_x, (geom.m0, geom.k0), 0, 256, dtype=jnp.int32
-        ).astype(jnp.uint8)
+        with jax.named_scope("rounding"):
+            r_u8 = jax.random.randint(
+                key_x, (geom.m0, geom.k0), 0, 256, dtype=jnp.int32
+            ).astype(jnp.uint8)
+            r_taps = per_tap(r_u8)
         in_specs.append(
             pl.BlockSpec((1, kk, bm, cb), lambda i, j, k: (k, 0, i, 0)))
-        operands.append(per_tap(r_u8))
+        operands.append(r_taps)
     in_specs += [SMEM_SPEC, SMEM_SPEC]  # stx, stp
     operands += [stx, stp]
     x_scale = None
@@ -588,15 +594,17 @@ def implicit_conv_forward(
         grouping=grouping, stochastic=stochastic, emit=emit_codes,
         bh=bh, n_k=n_k, x_scale=x_scale, w_scale=w_scale,
     )
-    res = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs if emit_codes else out_specs[0],
-        out_shape=out_shape if emit_codes else out_shape[0],
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
-    )(*operands)
+    with jax.named_scope("gemm"):
+        res = pl.pallas_call(
+            kernel,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=out_specs if emit_codes else out_specs[0],
+            out_shape=out_shape if emit_codes else out_shape[0],
+            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+            interpret=interpret,
+            name="implicit_conv",
+        )(*operands)
 
     y2d = res[0] if emit_codes else res
     y = y2d[:, : geom.o].reshape(geom.n, geom.oh, geom.ow, geom.o)

@@ -122,9 +122,9 @@ def _ref_quantize(x2d, fmt, k_block, gs_fmt, key, block_m, grouping,
     if key is None:
         r_u8 = jnp.full(x2d.shape, 127, dtype=jnp.uint8)
     else:
-        r_u8 = jax.random.randint(key, x2d.shape, 0, 256, dtype=jnp.int32).astype(
-            jnp.uint8
-        )
+        with jax.named_scope("rounding"):
+            r_u8 = jax.random.randint(
+                key, x2d.shape, 0, 256, dtype=jnp.int32).astype(jnp.uint8)
     return quantize_ref(
         x2d, fmt, k_block, gs_fmt=gs_fmt, r_u8=r_u8, grouping=grouping
     )
@@ -196,19 +196,21 @@ def qd_gemm(
             k_block=k_block, block_m=block_m, block_n=block_n,
         )
         block_m, block_n = cfg.block_m, cfg.block_n
-    xp = _pad_to(x2d.astype(jnp.float32), block_m, k_block)
-    wp = _pad_to(w2d.astype(jnp.float32), k_block, block_n)
-    xc, xsg, xst = backend.quantize(
-        xp, fmt, k_block, gs_fmt, key_x, block_m, grouping, interpret
-    )
-    wc, wsgT, wst = backend.quantize(
-        wp.T, fmt, k_block, gs_fmt, key_w, block_n, grouping, interpret
-    )
-    y = backend.matmul(
-        xc, xsg, xst, wc.T, wsgT.T, wst, fmt, k_block, block_m, block_n,
-        grouping, interpret,
-    )
-    return y[:M, :N]
+    with jax.named_scope("quantize"):
+        xp = _pad_to(x2d.astype(jnp.float32), block_m, k_block)
+        wp = _pad_to(w2d.astype(jnp.float32), k_block, block_n)
+        xc, xsg, xst = backend.quantize(
+            xp, fmt, k_block, gs_fmt, key_x, block_m, grouping, interpret
+        )
+        wc, wsgT, wst = backend.quantize(
+            wp.T, fmt, k_block, gs_fmt, key_w, block_n, grouping, interpret
+        )
+    with jax.named_scope("gemm"):
+        y = backend.matmul(
+            xc, xsg, xst, wc.T, wsgT.T, wst, fmt, k_block, block_m, block_n,
+            grouping, interpret,
+        )
+        return y[:M, :N]
 
 
 # ---------------------------------------------------------------------------
@@ -225,19 +227,19 @@ def _im2col(x: jax.Array, ksize: tuple[int, int], stride, padding):
     Feature order is (c, kh, kw), matching ``w.reshape(O, C*kh*kw)`` of an
     OIHW weight, so conv == cols @ w_mat.T.
     """
-    p = jax.lax.conv_general_dilated_patches(
-        x.astype(jnp.float32), ksize, stride, padding,
-        dimension_numbers=("NCHW", "OIHW", "NCHW"), precision=_EXACT,
-    )
-    n, ckk, oh, ow = p.shape
-    return p.transpose(0, 2, 3, 1).reshape(n * oh * ow, ckk), (n, oh, ow)
+    with jax.named_scope("im2col"):
+        p = jax.lax.conv_general_dilated_patches(
+            x.astype(jnp.float32), ksize, stride, padding,
+            dimension_numbers=("NCHW", "OIHW", "NCHW"), precision=_EXACT,
+        )
+        n, ckk, oh, ow = p.shape
+        return p.transpose(0, 2, 3, 1).reshape(n * oh * ow, ckk), (n, oh, ow)
 
 
 def _col2im(dcols: jax.Array, x_shape, ksize, stride, padding, out_hw):
     """Exact transpose of :func:`_im2col` (scatter-add of patch cotangents)."""
     n, oh, ow = out_hw
     ckk = dcols.shape[1]
-    dpatch = dcols.reshape(n, oh, ow, ckk).transpose(0, 3, 1, 2)
 
     def patches(a):
         return jax.lax.conv_general_dilated_patches(
@@ -245,11 +247,13 @@ def _col2im(dcols: jax.Array, x_shape, ksize, stride, padding, out_hw):
             dimension_numbers=("NCHW", "OIHW", "NCHW"), precision=_EXACT,
         )
 
-    transpose = jax.linear_transpose(
-        patches, jax.ShapeDtypeStruct(x_shape, jnp.float32)
-    )
-    (dx,) = transpose(dpatch)
-    return dx
+    with jax.named_scope("col2im"):
+        dpatch = dcols.reshape(n, oh, ow, ckk).transpose(0, 3, 1, 2)
+        transpose = jax.linear_transpose(
+            patches, jax.ShapeDtypeStruct(x_shape, jnp.float32)
+        )
+        (dx,) = transpose(dpatch)
+        return dx
 
 
 # ---------------------------------------------------------------------------
@@ -304,17 +308,19 @@ def _qd_gemm_precoded_x(
             k_block=k_block, block_m=block_m, block_n=block_n,
         )
         block_m, block_n = bc.block_m, bc.block_n
-    xcp = _pad_to(xc, block_m, k_block)  # zero codes decode to 0 — exact
-    wp = _pad_to(w2d.astype(jnp.float32), k_block, block_n)
-    wc, wsgT, wst = _pallas_quantize(
-        wp.T, fmt, k_block, gs_fmt, key_w, block_n, "none", interpret
-    )
-    ones = jnp.ones((1, 1), jnp.float32)
-    y = _pallas_matmul(
-        xcp, ones, x_st, wc.T, wsgT.T, wst, fmt, k_block, block_m, block_n,
-        "none", interpret,
-    )
-    return y[:M, :N]
+    with jax.named_scope("quantize"):
+        xcp = _pad_to(xc, block_m, k_block)  # zero codes decode to 0 — exact
+        wp = _pad_to(w2d.astype(jnp.float32), k_block, block_n)
+        wc, wsgT, wst = _pallas_quantize(
+            wp.T, fmt, k_block, gs_fmt, key_w, block_n, "none", interpret
+        )
+    with jax.named_scope("gemm"):
+        ones = jnp.ones((1, 1), jnp.float32)
+        y = _pallas_matmul(
+            xcp, ones, x_st, wc.T, wsgT.T, wst, fmt, k_block, block_m,
+            block_n, "none", interpret,
+        )
+        return y[:M, :N]
 
 
 def _conv_bwd_impl(x, w, g, key, stride, padding, cfg, backend):
@@ -337,8 +343,11 @@ def _conv_bwd_impl(x, w, g, key, stride, padding, cfg, backend):
         # forward activation codes are gathered as u8 instead of
         # re-quantizing the fp32 patch matrix (bit-identical to qd_gemm on
         # cols.T with grouping "none" + nearest rounding).
-        s_t, xp = covered_tensor_scale(x, geom)
-        colsT_codes = patches_u8(elementwise_codes(xp, s_t, cfg.fmt), geom).T
+        with jax.named_scope("quantize"):
+            s_t, xp = covered_tensor_scale(x, geom)
+            codes = elementwise_codes(xp, s_t, cfg.fmt)
+        with jax.named_scope("im2col"):
+            colsT_codes = patches_u8(codes, geom).T
         dwmat = _qd_gemm_precoded_x(
             colsT_codes, s_t, e2d, _maybe_key(key, cfg, 3),
             fmt=cfg.fmt, gs_fmt=cfg.gs_fmt, k_block=cfg.k_block,

@@ -259,5 +259,6 @@ def mls_matmul_pallas(
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=resolve_interpret(interpret),
+        name="mls_matmul",
     )(x_codes, x_sg, w_codes, w_sg, st)
     return out[:M, :N] if (pm or pn) else out

@@ -195,9 +195,9 @@ def mls_quantize_pallas(
     stochastic = key is not None
     r_u8 = None
     if stochastic:
-        r_u8 = jax.random.randint(key, x.shape, 0, 256, dtype=jnp.int32).astype(
-            jnp.uint8
-        )
+        with jax.named_scope("rounding"):
+            r_u8 = jax.random.randint(
+                key, x.shape, 0, 256, dtype=jnp.int32).astype(jnp.uint8)
 
     kp = padded_k(K, k_block)
     kt = k_tile(kp, k_block)
@@ -246,6 +246,7 @@ def mls_quantize_pallas(
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="mls_quantize",
     )(*operands)
     codes = res[0][:M, :K]
     if grouping == "nc":

@@ -41,6 +41,24 @@ def _fold(key, tag: int):
     return None if key is None else jax.random.fold_in(key, tag)
 
 
+# Activations and pooling run under the "act" scope, so a profile of the
+# compiled step can tell them from the conv, bn and fc layers.
+def _relu(x):
+    with jax.named_scope("act"):
+        return jax.nn.relu(x)
+
+
+def _max_pool(x, k: int, s: int, padding: str):
+    with jax.named_scope("act"):
+        return jax.lax.reduce_window(
+            x, -jnp.inf, jax.lax.max, (1, 1, k, k), (1, 1, s, s), padding)
+
+
+def _mean_pool(x):
+    with jax.named_scope("act"):
+        return jnp.mean(x, axis=(2, 3))
+
+
 # ---------------------------------------------------------------------------
 # ResNet (CIFAR basic-block and ImageNet basic-block variants)
 # ---------------------------------------------------------------------------
@@ -59,7 +77,7 @@ def _init_block(ks, c_in, c_out, stride):
 
 def _block(p, x, stride, qcfg, key, tag):
     h = nn.conv2d(p["conv1"], x, stride, "SAME", qcfg, _fold(key, tag))
-    h = jax.nn.relu(nn.batchnorm(p["bn1"], h))
+    h = _relu(nn.batchnorm(p["bn1"], h))
     h = nn.conv2d(p["conv2"], h, 1, "SAME", qcfg, _fold(key, tag + 1))
     h = nn.batchnorm(p["bn2"], h)
     if "proj" in p:
@@ -67,7 +85,7 @@ def _block(p, x, stride, qcfg, key, tag):
             p["bn_proj"],
             nn.conv2d(p["proj"], x, stride, "SAME", qcfg, _fold(key, tag + 2)),
         )
-    return jax.nn.relu(nn.ew_add(h, x))
+    return _relu(nn.ew_add(h, x))
 
 
 _RESNET_STAGES = {
@@ -103,11 +121,9 @@ def apply_resnet(p, x, cfg: CNNConfig, qcfg: QuantConfig | None, key=None):
     depths, widths, imagenet_stem = _RESNET_STAGES[cfg.arch]
     # first layer unquantized (paper Sec. VI-A)
     h = nn.conv2d(p["stem"], x, 2 if imagenet_stem else 1, "SAME", None)
-    h = jax.nn.relu(nn.batchnorm(p["bn_stem"], h))
+    h = _relu(nn.batchnorm(p["bn_stem"], h))
     if imagenet_stem:
-        h = jax.lax.reduce_window(
-            h, -jnp.inf, jax.lax.max, (1, 1, 3, 3), (1, 1, 2, 2), "SAME"
-        )
+        h = _max_pool(h, 3, 2, "SAME")
     bi_flat, tag = 0, 0
     for si, d in enumerate(depths):
         for bi in range(d):
@@ -115,7 +131,7 @@ def apply_resnet(p, x, cfg: CNNConfig, qcfg: QuantConfig | None, key=None):
             h = _block(p["blocks"][bi_flat], h, stride, qcfg, key, tag)
             bi_flat += 1
             tag += 3
-    h = jnp.mean(h, axis=(2, 3))
+    h = _mean_pool(h)
     return nn.linear(p["fc"], h, None)  # last layer unquantized
 
 
@@ -145,17 +161,15 @@ def apply_vgg16(p, x, cfg: CNNConfig, qcfg: QuantConfig | None, key=None):
     h, ci, tag = x, 0, 0
     for v in _VGG16:
         if v == "M":
-            h = jax.lax.reduce_window(
-                h, -jnp.inf, jax.lax.max, (1, 1, 2, 2), (1, 1, 2, 2), "VALID"
-            )
+            h = _max_pool(h, 2, 2, "VALID")
             continue
         q = None if ci == 0 else qcfg  # first conv unquantized
         blk = p["convs"][ci]
-        h = jax.nn.relu(nn.batchnorm(blk["bn"], nn.conv2d(
+        h = _relu(nn.batchnorm(blk["bn"], nn.conv2d(
             blk["conv"], h, 1, "SAME", q, _fold(key, tag))))
         ci += 1
         tag += 1
-    h = jnp.mean(h, axis=(2, 3))
+    h = _mean_pool(h)
     return nn.linear(p["fc"], h, None)
 
 
@@ -192,7 +206,7 @@ def _init_inception(ks, c_in, cfg: CNNConfig, spec):
 
 
 def _cbr(blk, x, k, stride, qcfg, key, tag):
-    return jax.nn.relu(nn.batchnorm(blk["bn"], nn.conv2d(
+    return _relu(nn.batchnorm(blk["bn"], nn.conv2d(
         blk["conv"], x, stride, "SAME", qcfg, _fold(key, tag))))
 
 
@@ -200,8 +214,7 @@ def _inception(p, x, qcfg, key, tag):
     b1 = _cbr(p["b1"], x, 1, 1, qcfg, key, tag)
     b3 = _cbr(p["b3"], _cbr(p["b3r"], x, 1, 1, qcfg, key, tag + 1), 3, 1, qcfg, key, tag + 2)
     b5 = _cbr(p["b5"], _cbr(p["b5r"], x, 1, 1, qcfg, key, tag + 3), 5, 1, qcfg, key, tag + 4)
-    pool = jax.lax.reduce_window(
-        x, -jnp.inf, jax.lax.max, (1, 1, 3, 3), (1, 1, 1, 1), "SAME")
+    pool = _max_pool(x, 3, 1, "SAME")
     bp = _cbr(p["bp"], pool, 1, 1, qcfg, key, tag + 5)
     return jnp.concatenate([b1, b3, b5, bp], axis=1)
 
@@ -231,20 +244,20 @@ def apply_googlenet(p, x, cfg: CNNConfig, qcfg: QuantConfig | None, key=None):
     imagenet = cfg.in_hw >= 128
     h = _cbr(p["stem1"], x, 7, 2 if imagenet else 1, None, None, 0)  # unquantized
     if imagenet:
-        h = jax.lax.reduce_window(h, -jnp.inf, jax.lax.max, (1, 1, 3, 3), (1, 1, 2, 2), "SAME")
+        h = _max_pool(h, 3, 2, "SAME")
     h = _cbr(p["stem2"], h, 1, 1, qcfg, key, 1)
     h = _cbr(p["stem3"], h, 3, 1, qcfg, key, 2)
     if imagenet:
-        h = jax.lax.reduce_window(h, -jnp.inf, jax.lax.max, (1, 1, 3, 3), (1, 1, 2, 2), "SAME")
+        h = _max_pool(h, 3, 2, "SAME")
     mi, tag = 0, 10
     for spec in _INCEPTION:
         if spec[0] == "M":
-            h = jax.lax.reduce_window(h, -jnp.inf, jax.lax.max, (1, 1, 3, 3), (1, 1, 2, 2), "SAME")
+            h = _max_pool(h, 3, 2, "SAME")
             continue
         h = _inception(p["inception"][mi], h, qcfg, key, tag)
         mi += 1
         tag += 6
-    h = jnp.mean(h, axis=(2, 3))
+    h = _mean_pool(h)
     return nn.linear(p["fc"], h, None)
 
 

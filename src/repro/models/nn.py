@@ -51,7 +51,8 @@ def _trace(kind: str, **dims):
 def ew_add(a: Array, b: Array) -> Array:
     """Element-wise residual add (traced: paper Table I counts these)."""
     _trace("ew_add", numel=int(jnp.size(a)))
-    return a + b
+    with jax.named_scope("act"):
+        return a + b
 
 
 # ---------------------------------------------------------------------------
@@ -98,31 +99,32 @@ def linear(p, x, qcfg: QuantConfig | None = None, key=None, wire=None):
         rows=int(jnp.size(x) // x.shape[-1]),
         quantized=qcfg is not None and qcfg.enabled,
     )
-    if qcfg is not None and qcfg.enabled:
-        if qcfg.backend == "pallas":
-            from repro.kernels import lowbit_matmul_qd
+    with jax.named_scope("fc"):
+        if qcfg is not None and qcfg.enabled:
+            if qcfg.backend == "pallas":
+                from repro.kernels import lowbit_matmul_qd
 
-            # quantized-domain path: the FSDP wire pinning is a fake-quant
-            # concern (the Pallas path already moves 1-byte codes).  The
-            # kernels honor qcfg.grouping / block_m / block_n — unset
-            # blocks resolve per-shape through the autotuner cache.
-            y = lowbit_matmul_qd(x, p["w"].astype(jnp.float32), key, qcfg)
+                # quantized-domain path: the FSDP wire pinning is a fake-quant
+                # concern (the Pallas path already moves 1-byte codes).  The
+                # kernels honor qcfg.grouping / block_m / block_n — unset
+                # blocks resolve per-shape through the autotuner cache.
+                y = lowbit_matmul_qd(x, p["w"].astype(jnp.float32), key, qcfg)
+            else:
+                if wire is not None and qcfg.wire_fsdp_dim != wire:
+                    import dataclasses as _dc
+
+                    qcfg = _dc.replace(qcfg, wire_fsdp_dim=wire)
+                y = lowbit_matmul(x, p["w"].astype(jnp.float32), key, qcfg)
         else:
-            if wire is not None and qcfg.wire_fsdp_dim != wire:
-                import dataclasses as _dc
-
-                qcfg = _dc.replace(qcfg, wire_fsdp_dim=wire)
-            y = lowbit_matmul(x, p["w"].astype(jnp.float32), key, qcfg)
-    else:
-        dt = x.dtype
-        y = jax.lax.dot_general(
-            x, p["w"].astype(dt),
-            (((x.ndim - 1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-    if "b" in p:
-        y = y + p["b"].astype(jnp.float32)
-    return y
+            dt = x.dtype
+            y = jax.lax.dot_general(
+                x, p["w"].astype(dt),
+                (((x.ndim - 1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+        if "b" in p:
+            y = y + p["b"].astype(jnp.float32)
+        return y
 
 
 def init_conv(key, c_in, c_out, ksize, dtype=jnp.float32):
@@ -140,17 +142,18 @@ def conv2d(p, x, stride=1, padding="SAME", qcfg: QuantConfig | None = None, key=
         h=x.shape[2] // s[0], w=x.shape[3] // s[1], n=x.shape[0],
         quantized=qcfg is not None and qcfg.enabled,
     )
-    if qcfg is not None and qcfg.enabled:
-        if qcfg.backend == "pallas":
-            from repro.kernels import lowbit_conv_fused
+    with jax.named_scope("conv"):
+        if qcfg is not None and qcfg.enabled:
+            if qcfg.backend == "pallas":
+                from repro.kernels import lowbit_conv_fused
 
-            return lowbit_conv_fused(x, p["w"], key, s, padding, qcfg)
-        return lowbit_conv(x, p["w"], key, s, padding, qcfg)
-    return jax.lax.conv_general_dilated(
-        x, p["w"].astype(x.dtype), s, padding,
-        dimension_numbers=("NCHW", "OIHW", "NCHW"),
-        preferred_element_type=jnp.float32,
-    )
+                return lowbit_conv_fused(x, p["w"], key, s, padding, qcfg)
+            return lowbit_conv(x, p["w"], key, s, padding, qcfg)
+        return jax.lax.conv_general_dilated(
+            x, p["w"].astype(x.dtype), s, padding,
+            dimension_numbers=("NCHW", "OIHW", "NCHW"),
+            preferred_element_type=jnp.float32,
+        )
 
 
 # ---------------------------------------------------------------------------
@@ -164,11 +167,12 @@ def batchnorm(p, x, eps=5e-5):
     """Training-mode BN over (N, H, W) of NCHW, fp32 (paper keeps BN full
     precision; eps matches paper Eq. 13)."""
     _trace("bn", numel=int(jnp.size(x)))
-    x = x.astype(jnp.float32)
-    mu = jnp.mean(x, axis=(0, 2, 3), keepdims=True)
-    var = jnp.mean(jnp.square(x), axis=(0, 2, 3), keepdims=True) - jnp.square(mu)
-    y = (x - mu) * jax.lax.rsqrt(var + eps)
-    return y * p["gamma"][None, :, None, None] + p["beta"][None, :, None, None]
+    with jax.named_scope("bn"):
+        x = x.astype(jnp.float32)
+        mu = jnp.mean(x, axis=(0, 2, 3), keepdims=True)
+        var = jnp.mean(jnp.square(x), axis=(0, 2, 3), keepdims=True) - jnp.square(mu)
+        y = (x - mu) * jax.lax.rsqrt(var + eps)
+        return y * p["gamma"][None, :, None, None] + p["beta"][None, :, None, None]
 
 
 def init_layernorm(d):

@@ -49,10 +49,11 @@ def sgdm_update(grads, state: OptState, params, lr, momentum=0.9, weight_decay=5
         m = momentum * m + g
         return m, (p.astype(jnp.float32) - lr * m).astype(p.dtype)
 
-    flat = jax.tree.map(upd, grads, state.mu, params)
-    mu = jax.tree.map(lambda t: t[0], flat, is_leaf=lambda t: isinstance(t, tuple))
-    new_p = jax.tree.map(lambda t: t[1], flat, is_leaf=lambda t: isinstance(t, tuple))
-    return new_p, OptState(state.step + 1, mu, ())
+    with jax.named_scope("optimizer"):
+        flat = jax.tree.map(upd, grads, state.mu, params)
+        mu = jax.tree.map(lambda t: t[0], flat, is_leaf=lambda t: isinstance(t, tuple))
+        new_p = jax.tree.map(lambda t: t[1], flat, is_leaf=lambda t: isinstance(t, tuple))
+        return new_p, OptState(state.step + 1, mu, ())
 
 
 # ---------------------------------------------------------------------------
@@ -65,10 +66,6 @@ def adamw_init(params) -> OptState:
 
 def adamw_update(grads, state: OptState, params, lr, b1=0.9, b2=0.95,
                  eps=1e-8, weight_decay=0.1):
-    t = state.step + 1
-    c1 = 1.0 - b1 ** t.astype(jnp.float32)
-    c2 = 1.0 - b2 ** t.astype(jnp.float32)
-
     def upd(g, m, v, p):
         g = g.astype(jnp.float32)
         m = b1 * m + (1 - b1) * g
@@ -77,12 +74,16 @@ def adamw_update(grads, state: OptState, params, lr, b1=0.9, b2=0.95,
         newp = p.astype(jnp.float32) * (1.0 - lr * weight_decay) - lr * u
         return m, v, newp.astype(p.dtype)
 
-    flat = jax.tree.map(upd, grads, state.mu, state.nu, params)
-    is3 = lambda t: isinstance(t, tuple)
-    mu = jax.tree.map(lambda t: t[0], flat, is_leaf=is3)
-    nu = jax.tree.map(lambda t: t[1], flat, is_leaf=is3)
-    new_p = jax.tree.map(lambda t: t[2], flat, is_leaf=is3)
-    return new_p, OptState(t, mu, nu)
+    with jax.named_scope("optimizer"):
+        t = state.step + 1
+        c1 = 1.0 - b1 ** t.astype(jnp.float32)
+        c2 = 1.0 - b2 ** t.astype(jnp.float32)
+        flat = jax.tree.map(upd, grads, state.mu, state.nu, params)
+        is3 = lambda t: isinstance(t, tuple)
+        mu = jax.tree.map(lambda t: t[0], flat, is_leaf=is3)
+        nu = jax.tree.map(lambda t: t[1], flat, is_leaf=is3)
+        new_p = jax.tree.map(lambda t: t[2], flat, is_leaf=is3)
+        return new_p, OptState(t, mu, nu)
 
 
 # ---------------------------------------------------------------------------

@@ -38,15 +38,17 @@ def make_cnn_train_step(
     def loss_fn(params, batch, i):
         key = jax.random.fold_in(jax.random.key(rounding_seed), i)
         logits = apply_cnn(params, batch["image"], cfg, qcfg, key)
-        ll = jax.nn.log_softmax(logits)
-        loss = -jnp.take_along_axis(ll, batch["label"][:, None], 1).mean()
-        acc = (logits.argmax(-1) == batch["label"]).mean()
+        with jax.named_scope("loss"):
+            ll = jax.nn.log_softmax(logits)
+            loss = -jnp.take_along_axis(ll, batch["label"][:, None], 1).mean()
+            acc = (logits.argmax(-1) == batch["label"]).mean()
         return loss, acc
 
     def step(params, opt, batch, i):
         (loss, acc), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             params, batch, i)
-        lr_t = lr(opt.step) if callable(lr) else lr
+        with jax.named_scope("optimizer"):
+            lr_t = lr(opt.step) if callable(lr) else lr
         params, opt = sgdm_update(grads, opt, params, lr_t)
         return params, opt, loss, acc
 
