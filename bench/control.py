@@ -76,7 +76,7 @@ def readings(cell: dict, seed: int, step, with_control: bool) -> dict:
     wd = cfg["weight_decay"]
     params, batch_of, k_ref, k_ctl = run.make_inputs(cell, seed)
     batches = [batch_of(j) for j in range(run.WARM_STEPS)]
-    p0_host = jax.device_get(params)
+    p0_host = run.host_copy(params)
     opt = jax.jit(sgdm_init)(params)
     if step is None:
         step = run.jit_program(cell)

@@ -19,36 +19,24 @@ The host half is read from the runtime's own spans: JAX's
 ``PjitFunction(<name>)`` events inside the benchmark's ``bench.dispatch``
 spans are the dispatch call, and every runtime event nested in them
 (``ParseArguments``, the executable's launch, its output-buffer
-allocations) keeps its self time by name.
+allocations) keeps its self time and its count by name.
 
-It runs one traced cell as ``run.py`` does and prints the split, from the
-root of a checkout on a TPU::
-
-    python bench/layers.py --workload <cell> --seed <n> --seconds <s>
-
-Standard error gets ``[scopes]``, ``[dispatch]`` and ``[gaps]`` lines; the
-last line of standard output is a JSON record of the same numbers.
+``trace.reduce_trace`` runs this reduction in the same pass as the class
+reduction; a traced ``run.py`` run prints :func:`report`'s ``[scopes]``,
+``[dispatch]`` and ``[gaps]`` lines on standard error.
 """
 from __future__ import annotations
 
 import dataclasses
-import os
 import re
-import sys
 
-if __name__ == "__main__":  # run as a script: the benchmark is a package
-    _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    sys.path[0] = _ROOT
-    sys.path.insert(1, os.path.join(_ROOT, "src"))
-
-from bench import trace  # noqa: E402
+from bench import trace
 
 SCOPES = ("conv", "fc", "bn", "act", "loss", "optimizer",
           "im2col", "col2im", "rounding", "quantize", "gemm")
 UNSCOPED = "unscoped"
 DISPATCH_EVENT = "PjitFunction("
 DISPATCH_SPAN = "bench.dispatch"
-MAIN_THREAD = "main/"  # the runtime's host line of the main thread
 
 _STEP_PREFIX = re.compile(r"jit\([^()]*\)/")
 _TOKEN = re.compile(r"[A-Za-z_]\w*")
@@ -144,10 +132,10 @@ def nest(events: list[tuple[str, int, int]]) -> Nested:
 
 
 def dispatch_split(nested: Nested, lo: int, hi: int
-                   ) -> tuple[float, dict[str, float]]:
+                   ) -> tuple[float, dict[str, float], dict[str, int]]:
     """(seconds in the union of ``PjitFunction`` events inside the
-    ``bench.dispatch`` spans that start in [lo, hi), self seconds by name
-    of every event inside those ``PjitFunction`` events, themselves
+    ``bench.dispatch`` spans that start in [lo, hi), self seconds and count
+    by name of every event inside those ``PjitFunction`` events, themselves
     included)."""
     evs = nested.events
     inside = [False] * len(evs)  # within a dispatch span of the window
@@ -161,10 +149,12 @@ def dispatch_split(nested: Nested, lo: int, hi: int
     union = trace.merge([(s, e) for i, (n, s, e) in enumerate(evs)
                          if calls[i] and n.startswith(DISPATCH_EVENT)])
     self_s: dict[str, float] = {}
+    count: dict[str, int] = {}
     for i, (name, _, _) in enumerate(evs):
         if calls[i]:
             self_s[name] = self_s.get(name, 0.0) + nested.self_ns[i] * 1e-9
-    return sum(e - s for s, e in union) * 1e-9, self_s
+            count[name] = count.get(name, 0) + 1
+    return sum(e - s for s, e in union) * 1e-9, self_s, count
 
 
 def gap_owners(nested: Nested, gaps: list[tuple[int, int]]
@@ -206,36 +196,6 @@ def gap_owners(nested: Nested, gaps: list[tuple[int, int]]
     return out
 
 
-def read_bench_thread(path: str) -> list[tuple[str, int, int]]:
-    """Every event of the host thread that runs the benchmark's loop (read
-    gzip-compressed where the name ends in ``.gz``).  The profiler writes
-    that thread as two lines: the one that holds the benchmark's
-    ``bench.window`` span (Python's spans, ``PjitFunction``,
-    ``ParseArguments``) and the runtime's line of the process's main thread,
-    ``main/<tid>`` (buffer allocation, waits on buffer holds, the
-    executable's launch)."""
-    from jax.profiler import ProfileData
-
-    if path.endswith(".gz"):
-        import gzip
-
-        with gzip.open(path, "rb") as f:
-            data = ProfileData.from_serialized_xspace(f.read())
-    else:
-        data = ProfileData.from_file(path)
-    lines = [(line.name, [(ev.name, int(ev.start_ns), int(ev.end_ns))
-                          for ev in line.events])
-             for plane in data.planes if not plane.name.startswith("/device:")
-             for line in plane.lines]
-    spans = next((i for i, (_, evs) in enumerate(lines)
-                  if any(n == trace.WINDOW_SPAN for n, _, _ in evs)), None)
-    if spans is None:
-        return []
-    return lines[spans][1] + [ev for i, (name, evs) in enumerate(lines)
-                              if i != spans and name.startswith(MAIN_THREAD)
-                              for ev in evs]
-
-
 # ---------------------------------------------------------------------------
 # the reduction
 # ---------------------------------------------------------------------------
@@ -249,6 +209,7 @@ class Layers:
     dispatch_s: float  # union of PjitFunction events in bench.dispatch
     bench_dispatch_s: float  # the bench.dispatch spans themselves
     runtime_self_s: dict[str, float]  # self seconds by runtime event name
+    runtime_count: dict[str, int]  # events by runtime event name
     gap_owners: list[tuple[str, float, float]]  # longest idle gaps first
 
     def ms_per_step(self, seconds: float) -> float | None:
@@ -284,7 +245,7 @@ def reduce_layers(device_ops: dict[str, list[tuple[str, int, int]]],
                 key = "+".join(sorted(mixes[name]))
                 mixed_s[key] = mixed_s.get(key, 0.0) + (e - s) * 1e-9 / chips
         all_gaps.extend(trace.gaps(trace.merge(clipped), lo, hi))
-    dispatch_s, self_s = dispatch_split(nested, lo, hi)
+    dispatch_s, self_s, count = dispatch_split(nested, lo, hi)
     longest = sorted(all_gaps, key=lambda g: g[0] - g[1])[:top]
     return Layers(
         steps=sum(1 for n, s, _ in nested.events
@@ -292,12 +253,8 @@ def reduce_layers(device_ops: dict[str, list[tuple[str, int, int]]],
         scope_s=scope_s, mixed_s=mixed_s, dispatch_s=dispatch_s,
         bench_dispatch_s=sum(e - s for n, s, e in nested.events
                              if n == DISPATCH_SPAN and lo <= s < hi) * 1e-9,
-        runtime_self_s=self_s, gap_owners=gap_owners(nested, longest))
-
-
-def reduce_layers_trace(path: str, hlo_text: str) -> Layers:
-    device_ops, _ = trace.read_xspace(path)
-    return reduce_layers(device_ops, read_bench_thread(path), hlo_text)
+        runtime_self_s=self_s, runtime_count=count,
+        gap_owners=gap_owners(nested, longest))
 
 
 def report(lay: Layers) -> list[str]:
@@ -310,93 +267,14 @@ def report(lay: Layers) -> list[str]:
     mixed = {k: ms(v) for k, v in sorted(lay.mixed_s.items(),
                                          key=lambda kv: -kv[1])}
     top = sorted(lay.runtime_self_s.items(), key=lambda kv: -kv[1])[:8]
+    calls = {k: round(lay.runtime_count[k] / max(lay.steps, 1), 2)
+             for k, _ in top}
     return [
         f"[scopes] ms per step {scopes}; total "
         f"{ms(sum(lay.scope_s.values()))}; fusions mixing scopes {mixed}",
         f"[dispatch] PjitFunction {ms(lay.dispatch_s)} ms per step of "
-        f"bench.dispatch {ms(lay.bench_dispatch_s)}; top self ms per step "
-        f"{ {k: ms(v) for k, v in top} }",
+        f"bench.dispatch {ms(lay.bench_dispatch_s)}; top self time per step "
+        f"(name: [ms, events]) { {k: [ms(v), calls[k]] for k, v in top} }",
         "[gaps] " + "; ".join(f"{g * 1e3:.3f} ms under {owner} ({share:.0%})"
                               for owner, share, g in lay.gap_owners),
     ]
-
-
-# ---------------------------------------------------------------------------
-# one traced run of a cell
-# ---------------------------------------------------------------------------
-def scoped_hlo(cell: dict, seed: int) -> str:
-    """The compiled step's HLO text with this checkout's metadata.  The
-    persistent cache's key leaves metadata out, so a cached executable may
-    carry another checkout's; the key takes it in for this compile."""
-    import jax
-    import numpy as np
-
-    from bench import run
-    from repro.optim import sgdm_init
-
-    params, batch_of, _, _ = run.make_inputs(cell, seed)
-    opt = jax.jit(sgdm_init)(params)
-    key = "jax_compilation_cache_include_metadata_in_key"
-    was = getattr(jax.config, key)
-    jax.config.update(key, True)
-    try:
-        return run.jit_program(cell).lower(
-            params, opt, batch_of(0), np.int32(0)).compile().as_text()
-    finally:
-        jax.config.update(key, was)
-
-
-def main(argv=None) -> int:
-    import argparse
-    import json
-
-    from bench import run
-
-    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--workload", required=True)
-    ap.add_argument("--seed", type=int, required=True)
-    ap.add_argument("--seconds", type=float, required=True)
-    args = ap.parse_args(argv)
-    try:
-        cell = run.load_cell(args.workload)
-        dev, _ = run.check_device(cell["chips"])
-        run.enable_compile_cache()
-        hlo_text = scoped_hlo(cell, args.seed)
-        seen: list[Layers] = []
-        reduce_classes = trace.reduce_trace
-
-        def reduce_both(path: str, text: str):
-            seen.append(reduce_layers_trace(path, hlo_text))
-            return reduce_classes(path, text)
-
-        trace.reduce_trace = reduce_both  # read before run.py deletes it
-        try:
-            record = run.run_cell(cell, args.seed, args.seconds, True, dev=dev)
-        finally:
-            trace.reduce_trace = reduce_classes
-    except (run.BenchError, FileNotFoundError) as e:
-        print(f"layers: FAILED: {e}", file=sys.stderr, flush=True)
-        return 1
-    lay, red = seen[0], record["trace"]
-    for line in report(lay):
-        print(line, file=sys.stderr, flush=True)
-    out = {"correct": record["correct"], "steps": lay.steps,
-           "xla.ms_per_step": red.ms_per_step("xla"),
-           "class_ms_per_step": {k: red.ms_per_step(k) for k in red.class_s},
-           "scope_ms_per_step": {k: lay.ms_per_step(v)
-                                 for k, v in lay.scope_s.items()},
-           "mixed_ms_per_step": {k: lay.ms_per_step(v)
-                                 for k, v in lay.mixed_s.items()},
-           "dispatch_ms_per_step": lay.ms_per_step(lay.dispatch_s),
-           "bench_dispatch_ms_per_step": lay.ms_per_step(lay.bench_dispatch_s),
-           "runtime_self_ms_per_step": {
-               k: lay.ms_per_step(v) for k, v in sorted(
-                   lay.runtime_self_s.items(), key=lambda kv: -kv[1])[:20]},
-           "gap_owners": lay.gap_owners,
-           "device": {"platform": dev.platform, "kind": dev.device_kind}}
-    print(json.dumps(out), flush=True)
-    return 0
-
-
-if __name__ == "__main__":
-    sys.exit(main())

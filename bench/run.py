@@ -7,17 +7,24 @@ One run is one process on one chip::
 It loads the cell (``bench/workloads/<cell>.json``, which names its
 configuration ``bench/configs/<config>.json``), makes the weights and a
 pool of distinct batches on the device from ``--seed``, compiles the
-program's jitted train step (``repro.train.make_cnn_train_step``), drives
-it through three steps that the plain reference (``reference.py``) checks
+program's train step (``repro.train.make_cnn_train_step``), drives it
+through three steps that the plain reference (``reference.py``) checks
 after the window, then trains for ``--seconds`` in a closed loop (one step
-in flight, loss read back after each).  With ``--trace 0`` the last line
-of standard output holds the cell's end-to-end metrics; with ``--trace 1``
-the window is profiled and the line holds the per-layer metrics
-(``bench/metrics/<metric>.py``) that ``BENCHMARK.json`` lists for the cell.
+in flight, loss read back after each).  The step is called as the program
+hands it out: one the program has jitted already, with whatever arguments
+it donates, is called as it is, and any other is put under ``jax.jit``
+(``jit_program``).  With ``--trace 0`` the last line of standard output
+holds the cell's end-to-end metrics; with ``--trace 1`` the window is
+profiled, the line holds the per-layer metrics
+(``bench/metrics/<metric>.py``) that ``BENCHMARK.json`` lists for the
+cell, and standard error gets the device time per program scope and the
+host's dispatch call by runtime event (``layers.report``).
 
 The run fails, printing no result, when JAX finds no TPU or fewer chips
 than the cell asks for, when the chip's kind is not in ``peaks.json``,
-and when anything compiles inside the window.
+when the step consumes its batch (the pool is reused; a step may donate
+only its parameters and optimizer state), and when anything compiles
+inside the window.
 """
 from __future__ import annotations
 
@@ -249,19 +256,49 @@ def run_reference(cell: dict, p0_host, batch_of, key, num,
 
 
 def jit_program(cell: dict, step_wrapper=None):
-    """The program's train step under ``jax.jit``, as its users call it;
-    its first call compiles."""
+    """The program's train step (under ``step_wrapper`` where one is given)
+    as the program hands it out, as its users call it; its first call
+    compiles.  A step that is jitted already (it has ``lower``) is called as
+    it is: an outer ``jax.jit`` would drop the arguments the program's own
+    jit donates.  Any other step is put under ``jax.jit``."""
     import jax
 
     fn = program_step(cell)
     if step_wrapper is not None:
         fn = step_wrapper(fn)
-    return jax.jit(fn)
+    return fn if hasattr(fn, "lower") else jax.jit(fn)
+
+
+def host_copy(tree):
+    """The tree's arrays read back into host memory of their own.  On the
+    CPU ``jax.device_get`` gives views of the device buffers, and a step
+    cannot consume a donated array while a view of it lives."""
+    import jax
+    import numpy as np
+
+    return jax.tree.map(np.array, jax.device_get(tree))
+
+
+def compiled_hlo(step, *args) -> str:
+    """The compiled step's HLO text with this checkout's metadata.  The
+    persistent cache's key leaves metadata out, so a cached executable may
+    carry another checkout's ``op_name``s; the key takes it in here."""
+    import jax
+
+    key = "jax_compilation_cache_include_metadata_in_key"
+    was = getattr(jax.config, key)
+    jax.config.update(key, True)
+    try:
+        return step.lower(*args).compile().as_text()
+    finally:
+        jax.config.update(key, was)
 
 
 def first_steps(step, params, opt, batches, p0_host):
-    """Drive the jitted step through the set-up steps on batches 0..2 (the
-    first compiles); return the state and what the check reads of them."""
+    """Drive the step through the set-up steps on batches 0..2 (the first
+    compiles); return the state and what the check reads of them.  The step
+    may consume (donate) ``params`` and ``opt``, and nothing else: the
+    window reuses the pool of batches."""
     import jax
     import numpy as np
 
@@ -270,8 +307,13 @@ def first_steps(step, params, opt, batches, p0_host):
         params, opt, loss, _ = step(params, opt, batches[i], np.int32(i))
         prog["losses"].append(float(loss))
         if i == 0:
-            prog["mu1"] = jax.tree.leaves(jax.device_get(opt.mu))
-    prog["p3"] = jax.tree.leaves(jax.device_get(params))
+            prog["mu1"] = jax.tree.leaves(host_copy(opt.mu))
+    if any(x.is_deleted() for x in jax.tree.leaves(batches[:WARM_STEPS])):
+        raise BenchError("the program's step consumed its batch (it donates "
+                         "argument 2); the benchmark reuses its pool of "
+                         "batches, so a step may donate only its parameters "
+                         "and optimizer state (arguments 0 and 1)")
+    prog["p3"] = jax.tree.leaves(host_copy(params))
     return params, opt, prog
 
 
@@ -288,13 +330,12 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     watch = CompileWatch()
     params, batch_of, k_ref, _ = make_inputs(cell, seed)
     batches = [batch_of(j) for j in range(wl["pool"])]
-    p0_host = jax.device_get(params)
+    p0_host = host_copy(params)
     opt = jax.jit(sgdm_init)(params)
     step = jit_program(cell, step_wrapper)
     hlo_text = ""
-    if trace:  # the compiled step's HLO sorts the trace's device ops
-        hlo_text = step.lower(params, opt, batches[0],
-                              np.int32(0)).compile().as_text()
+    if trace:  # its HLO sorts the trace's device ops by class and scope
+        hlo_text = compiled_hlo(step, params, opt, batches[0], np.int32(0))
     params, opt, prog = first_steps(step, params, opt, batches, p0_host)
     setup_s = time.perf_counter() - T_START
 
@@ -362,14 +403,17 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     del params, opt, batches, step, batch, loss, acc
     gc.collect()
     if trace:
+        from bench import layers
         from bench import trace as bench_trace
 
         path = bench_trace.find_xspace(trace_dir)
-        record["trace"], unexpected = bench_trace.reduce_trace(path,
-                                                               hlo_text)
+        record["trace"], lay, unexpected = bench_trace.reduce_trace(path,
+                                                                    hlo_text)
         if unexpected:
             _say(f"[trace] {len(unexpected)} tpu_custom_call(s) that no class "
                  f"rule expects, counted as gemm: {unexpected[:5]}")
+        for line in layers.report(lay):
+            _say(line)
         shutil.rmtree(trace_dir, ignore_errors=True)
 
     t_ref = time.perf_counter()

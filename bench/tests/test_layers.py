@@ -5,7 +5,7 @@ import os
 
 import pytest
 
-from bench import layers, trace
+from bench import layers, run, trace
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 
@@ -116,6 +116,8 @@ def test_reduce_layers_by_hand():
         "ParseArguments": pytest.approx((6 + 10) * ns),
         "Wait for donation holds": pytest.approx(5 * ns)}
     assert sum(lay.runtime_self_s.values()) == pytest.approx(lay.dispatch_s)
+    assert lay.runtime_count == {"PjitFunction(step)": 3, "ParseArguments": 2,
+                                 "Wait for donation holds": 2}
     # idle gaps of 10, 20, 10, 40 and 30 ns: in 150..170 readback's
     # np.asarray covers 19 ns; in 200..240 the PjitFunction covers 39 ns
     # (its ParseArguments only 10, under half the gap)
@@ -157,10 +159,8 @@ def test_recorded_chip_trace():
     dispatch span.  The Python line holds ``PjitFunction(jit(step))``, nested
     in itself, and ``ParseArguments``; the runtime's line of the main thread
     holds the execute call, with three output-buffer allocations each."""
-    path = os.path.join(DATA, "small.xplane.pb.gz")
-    with gzip.open(os.path.join(DATA, "small.hlo.txt.gz"), "rt") as f:
-        hlo = f.read()
-    lay = layers.reduce_layers_trace(path, hlo)
+    ops, _, thread = trace.read_xspace(os.path.join(DATA, "small.xplane.pb.gz"))
+    lay = layers.reduce_layers(ops, thread, _recorded_hlo())
     assert lay.steps == 2
     # no scope in that program: every device op is unscoped, and the total
     # is the class total test_trace.py reads by hand
@@ -177,6 +177,8 @@ def test_recorded_chip_trace():
     assert self_s["DeferredTpuAllocator::Allocate"] == pytest.approx(
         (92_730 + 85_090 + 64_131 + 83_900 + 92_940 + 85_260) * 1e-9)
     assert self_s["ParseArguments"] == pytest.approx((3_420 + 4_390) * 1e-9)
+    assert lay.runtime_count["DeferredTpuAllocator::Allocate"] == 6
+    assert lay.runtime_count["PjitFunction(jit(step))"] == 4
     # the buffer-hold waits follow the call inside bench.dispatch: not in it
     assert "Wait for donation holds" not in self_s
     # the longest idle gap: the first call's key derivation compiled there
@@ -185,10 +187,77 @@ def test_recorded_chip_trace():
     assert owner.endswith(">PJRT_Client_Compile")
 
 
-def test_no_tpu_fails_closed(capsys):
-    rc = layers.main(["--workload", "resnet20-cifar.plain", "--seed", "1",
-                      "--seconds", "1"])
-    out, err = capsys.readouterr()
-    assert rc != 0
-    assert "no TPU found" in err
-    assert out == ""
+def _recorded_hlo() -> str:
+    with gzip.open(os.path.join(DATA, "small.hlo.txt.gz"), "rt") as f:
+        return f.read()
+
+
+SCOPE_METRICS = {"conv.ms_per_step": "conv", "bn.ms_per_step": "bn",
+                 "optimizer.ms_per_step": "optimizer",
+                 "unscoped.ms_per_step": "unscoped"}
+
+
+def _read(red) -> dict:
+    ctx = {"cell": None, "trace": red, "peaks": {}}
+    return {name: run.read_metric(name, ctx)
+            for name in ("dispatch.ms_per_step", *SCOPE_METRICS)}
+
+
+def _expected(lay) -> dict:
+    out = {"dispatch.ms_per_step": lay.ms_per_step(lay.dispatch_s)}
+    for name, scope in SCOPE_METRICS.items():
+        out[name] = lay.ms_per_step(lay.scope_s[scope])
+    return out
+
+
+def test_metrics_read_the_window_by_hand():
+    """The dispatch and scope metrics read what ``reduce_layers`` gives,
+    through the same pass that reduces the device classes."""
+    ops, host = _window()
+    spans: dict[str, list[tuple[int, int]]] = {}
+    for name, s, e in host:
+        spans.setdefault(name, []).append((s, e))
+    red, lay, _ = trace.reduce_window(ops, spans, host, HLO)
+    assert lay == layers.reduce_layers(ops, host, HLO)
+    got = _read(red)
+    assert got == _expected(lay)
+    ns_ms = 1e-9 * 1e3
+    assert got == {"dispatch.ms_per_step": pytest.approx(48 * ns_ms),
+                   "conv.ms_per_step": pytest.approx(10 * ns_ms),
+                   "bn.ms_per_step": pytest.approx(25 * ns_ms),
+                   "optimizer.ms_per_step": None,  # nothing in that scope
+                   "unscoped.ms_per_step": pytest.approx(15 * ns_ms)}
+    classes, _ = trace.classify_hlo(HLO)
+    assert red.class_s == trace.reduce_events(ops, spans, classes).class_s
+
+
+def test_metrics_read_the_recorded_chip_trace():
+    """On the recorded trace (no scopes in its program) the dispatch metric
+    reads the two calls' ``PjitFunction`` time, ``unscoped`` every device
+    op, the other scopes nothing; every field that the reduction had before
+    reads as ``reduce_events`` gives it."""
+    hlo = _recorded_hlo()
+    path = os.path.join(DATA, "small.xplane.pb.gz")
+    red, lay, unexpected = trace.reduce_trace(path, hlo)
+    ops, host, thread = trace.read_xspace(path)
+    assert lay == layers.reduce_layers(ops, thread, hlo)
+    got = _read(red)
+    assert got == _expected(lay)
+    assert got["dispatch.ms_per_step"] == pytest.approx(
+        (443_750 + 502_980) / 2 * 1e-6)
+    assert got["unscoped.ms_per_step"] == pytest.approx(36_353 / 2 * 1e-6)
+    assert got["conv.ms_per_step"] is None
+    classes, _ = trace.classify_hlo(hlo)
+    before = trace.reduce_events(ops, host, classes)
+    for field in ("window_s", "busy_s", "steps", "class_s", "top_ops",
+                  "idle_gaps", "chips"):
+        assert getattr(red, field) == getattr(before, field), field
+    assert unexpected == []
+
+
+def test_report_counts_runtime_events_per_step():
+    ops, host = _window()
+    lines = layers.report(layers.reduce_layers(ops, host, HLO))
+    assert lines[1].startswith("[dispatch] PjitFunction")
+    assert "'ParseArguments': [0.0, 1.0]" in lines[1]
+    assert "'PjitFunction(step)': [0.0, 1.5]" in lines[1]

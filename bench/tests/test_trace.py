@@ -119,7 +119,7 @@ def test_recorded_chip_trace():
     assert classes == {"step.3": "quantize", "step.4": "quantize",
                        "step.5": "gemm"}
     assert unexpected == []
-    ops, host = trace.read_xspace(os.path.join(DATA, "small.xplane.pb.gz"))
+    ops, host, _ = trace.read_xspace(os.path.join(DATA, "small.xplane.pb.gz"))
     red = trace.reduce_events(ops, host, classes)
     assert red.steps == 2 and red.chips == 1
     assert red.window_s == pytest.approx(237_478_905e-9)

@@ -15,7 +15,9 @@ sorted by the compiled step's own HLO, by its result and operand types:
 Busy time is the union of the device operations' intervals inside the
 window (the benchmark's ``bench.window`` host span); idle gaps are the
 rest of the window, each labelled by the benchmark's host span that
-overlaps it most.
+overlaps it most.  The same pass reads the device time per program scope
+and the host's dispatch call from the benchmark thread's events
+(``layers.py``).
 """
 from __future__ import annotations
 
@@ -29,6 +31,7 @@ WINDOW_SPAN = "bench.window"
 STEP_SPAN = "bench.step"
 HOST_SPANS = ("bench.data_fetch", "bench.dispatch", "bench.readback")
 OPS_LINE = "XLA Ops"
+MAIN_THREAD = "main/"  # the runtime's host line of the main thread
 
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*?)\s+custom-call\(")
 _OP_NAME = re.compile(r"^%?([\w.\-]+)\s*=")
@@ -81,11 +84,21 @@ class Reduced:
     top_ops: list[tuple[str, float]]  # (class:name, seconds), largest first
     idle_gaps: list[tuple[str, float]]  # (host span, seconds), longest first
     chips: int
+    # device seconds per program scope, mean over chips (layers.SCOPES)
+    scope_s: dict[str, float] = dataclasses.field(default_factory=dict)
+    dispatch_s: float = 0.0  # union of PjitFunction events in bench.dispatch
+    # self seconds by name of the runtime events inside those calls
+    runtime_self_s: dict[str, float] = dataclasses.field(default_factory=dict)
 
     def ms_per_step(self, cls: str) -> float | None:
-        if self.steps == 0 or self.class_s.get(cls, 0.0) <= 0.0:
+        return self.per_step(self.class_s.get(cls, 0.0))
+
+    def per_step(self, seconds: float) -> float | None:
+        """``seconds`` of the window in ms per step; None where the window
+        holds no step or nothing was read."""
+        if self.steps == 0 or seconds <= 0.0:
             return None
-        return self.class_s[cls] / self.steps * 1e3
+        return seconds / self.steps * 1e3
 
 
 def merge(intervals: list[tuple[int, int]]) -> list[tuple[int, int]]:
@@ -169,8 +182,15 @@ def op_name(event_name: str) -> str:
 
 
 def read_xspace(path: str):
-    """Device ops and the benchmark's host spans of one ``.xplane.pb``
-    (read gzip-compressed where the name ends in ``.gz``)."""
+    """Device ops, the benchmark's host spans, and every event of the host
+    thread that runs the benchmark's loop, of one ``.xplane.pb`` (read
+    gzip-compressed where the name ends in ``.gz``).
+
+    The profiler writes that thread as two lines: the one that holds the
+    benchmark's ``bench.window`` span (Python's spans, ``PjitFunction``,
+    ``ParseArguments``) and the runtime's line of the process's main thread,
+    ``main/<tid>`` (buffer allocation, waits on buffer holds, the
+    executable's launch)."""
     from jax.profiler import ProfileData
 
     if path.endswith(".gz"):
@@ -182,6 +202,7 @@ def read_xspace(path: str):
         data = ProfileData.from_file(path)
     device_ops: dict[str, list[tuple[str, int, int]]] = {}
     host: dict[str, list[tuple[int, int]]] = {}
+    lines: list[tuple[str, list[tuple[str, int, int]]]] = []
     wanted = {WINDOW_SPAN, STEP_SPAN, *HOST_SPANS}
     for plane in data.planes:
         if _DEVICE_PLANE.match(plane.name):
@@ -192,13 +213,20 @@ def read_xspace(path: str):
                         for ev in line.events)
         elif not plane.name.startswith("/device:"):
             for line in plane.lines:
-                for ev in line.events:
-                    if ev.name in wanted:
-                        host.setdefault(ev.name, []).append(
-                            (int(ev.start_ns), int(ev.end_ns)))
+                events = [(ev.name, int(ev.start_ns), int(ev.end_ns))
+                          for ev in line.events]
+                lines.append((line.name, events))
+                for name, s, e in events:
+                    if name in wanted:
+                        host.setdefault(name, []).append((s, e))
     for spans in host.values():
         spans.sort()
-    return device_ops, host
+    spans_line = next((i for i, (_, evs) in enumerate(lines)
+                       if any(n == WINDOW_SPAN for n, _, _ in evs)), None)
+    thread = [] if spans_line is None else lines[spans_line][1] + [
+        ev for i, (name, evs) in enumerate(lines)
+        if i != spans_line and name.startswith(MAIN_THREAD) for ev in evs]
+    return device_ops, host, thread
 
 
 def find_xspace(trace_dir: str) -> str:
@@ -210,7 +238,22 @@ def find_xspace(trace_dir: str) -> str:
     return found[-1]
 
 
-def reduce_trace(path: str, hlo_text: str) -> tuple[Reduced, list[str]]:
+def reduce_window(device_ops: dict[str, list[tuple[str, int, int]]],
+                  host: dict[str, list[tuple[int, int]]],
+                  thread: list[tuple[str, int, int]], hlo_text: str):
+    """The :class:`Reduced` of one window, with its scope and dispatch
+    fields, the :class:`layers.Layers` they come from, and the custom
+    calls no class rule expects."""
+    from bench import layers  # it builds on this module
+
     classes, unexpected = classify_hlo(hlo_text)
-    device_ops, host = read_xspace(path)
-    return reduce_events(device_ops, host, classes), unexpected
+    red = reduce_events(device_ops, host, classes)
+    lay = layers.reduce_layers(device_ops, thread, hlo_text)
+    red.scope_s = dict(lay.scope_s)
+    red.dispatch_s = lay.dispatch_s
+    red.runtime_self_s = dict(lay.runtime_self_s)
+    return red, lay, unexpected
+
+
+def reduce_trace(path: str, hlo_text: str):
+    return reduce_window(*read_xspace(path), hlo_text)
