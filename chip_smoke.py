@@ -71,12 +71,11 @@ def check_device():
 
 
 def compile_step(cfg, qcfg, params, opt, batch, seed: int, tag: str):
-    import jax
     import jax.numpy as jnp
 
     from repro.train import make_cnn_train_step
 
-    step = jax.jit(make_cnn_train_step(cfg, qcfg, LR, rounding_seed=seed))
+    step = make_cnn_train_step(cfg, qcfg, LR, rounding_seed=seed)
     t0 = time.perf_counter()
     lowered = step.lower(params, opt, batch, jnp.int32(0))
     t1 = time.perf_counter()

@@ -35,7 +35,7 @@ def train(variant, qcfg, args, ckpt_dir=None):
     mgr = CheckpointManager(ckpt_dir, keep=2) if ckpt_dir else None
     mon = StragglerMonitor()
 
-    step = jax.jit(make_cnn_train_step(cfg, qcfg, lr_fn, rounding_seed=7))
+    step = make_cnn_train_step(cfg, qcfg, lr_fn, rounding_seed=7)
 
     accs = []
     for i in range(args.steps):

@@ -55,7 +55,7 @@ def _train_cnn(cell: Cell) -> tuple[float, float | None]:
     nxt, ds = make_cifar_iterator(batch=cell.batch, hw=cell.hw,
                                   num_classes=_NUM_CLASSES, seed=cell.seed)
 
-    step = jax.jit(make_cnn_train_step(cfg, qcfg, cell.lr, rounding_seed=1))
+    step = make_cnn_train_step(cfg, qcfg, cell.lr, rounding_seed=1)
 
     losses, accs = [], []
     for i in range(cell.steps):

@@ -31,8 +31,14 @@ def make_cnn_train_step(
 
     ``lr`` is a constant or a schedule of the optimizer step count.  The
     stochastic-rounding key of step ``i`` is ``fold_in(key(rounding_seed),
-    i)``.  The step is returned un-jitted: callers ``jax.jit`` it (or
-    lower it ahead of time).
+    i)``.
+
+    The step comes back jitted, donating ``params`` and ``opt``: a call
+    consumes the ones it is given (their buffers hold the new state), so
+    callers rebind both to its outputs.  The batch and ``i`` are not
+    donated.  Call it as it is, or lower it ahead of time with
+    ``step.lower(...)``; an outer ``jax.jit`` around it drops the donation
+    (JAX 0.9.0).
     """
 
     def loss_fn(params, batch, i):
@@ -52,4 +58,4 @@ def make_cnn_train_step(
         params, opt = sgdm_update(grads, opt, params, lr_t)
         return params, opt, loss, acc
 
-    return step
+    return jax.jit(step, donate_argnums=(0, 1))

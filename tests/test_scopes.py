@@ -44,7 +44,7 @@ def compiled_plain_step(batch: int = 8) -> str:
              "label": jax.ShapeDtypeStruct((batch,), jnp.int32)},
             jax.ShapeDtypeStruct((), jnp.int32))
     step = make_cnn_train_step(cfg, None, 0.1, rounding_seed=0)
-    return jax.jit(step).lower(*args).compile().as_text()
+    return step.lower(*args).compile().as_text()
 
 
 def strip_metadata(hlo_text: str) -> list[str]:
